@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 # Deployment-configuration search on the production mesh — the paper's
 # technique as a first-class framework feature (§Perf driver).
 #
@@ -10,9 +7,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # Samples persist in experiments/tuning_store.db: rerunning (any optimizer)
 # transparently reuses earlier compilations (paper Fig. 7 behaviour), and
 # `--transfer-from <arch>` seeds a new architecture's search via RSSC.
+#
+# Run as a script it asks XLA for 512 placeholder host devices, before any
+# backend is touched (jax locks the device count on first init).
 
 import argparse
 import json
+import os
 
 from repro.launch.mesh import make_production_mesh
 from repro.tuning.hillclimb import hillclimb_cell, transfer_tuning
@@ -56,4 +57,5 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

@@ -24,6 +24,9 @@ configurations through the spec's first experiment/connector and captures
 the actuation trace (phase outcomes, durations, retries, properties) to a
 JSONL file replayable via the ``trace-replay`` factory — pay for a sweep
 once, replay it forever.
+
+Run as a script, ``run`` keeps the programs its connectors compile in the
+persistent compilation cache (:mod:`repro.launch.compile_cache`).
 """
 
 from __future__ import annotations
@@ -212,4 +215,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    if sys.argv[1:2] == ["run"]:
+        from ...launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     sys.exit(main())

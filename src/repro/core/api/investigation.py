@@ -40,7 +40,7 @@ import numpy as np
 from ..campaign import MemberResult, _drive_fleet, _Member
 from ..clustering import select_indices
 from ..discovery import DiscoverySpace
-from ..execution import ExecutionBackend
+from ..execution import ExecutionBackend, ProcessBackend, QueueBackend
 from ..optimizers.base import (OptimizerRun, SearchAdapter, _StoppingRule,
                                as_scored)
 from ..store import StoreBackend, open_store
@@ -435,6 +435,7 @@ class Investigation:
         """Describe the run without measuring anything: engine dispatch,
         fleet, budget, and — when transfer is enabled — the related spaces
         the catalog would offer as warm-start sources."""
+        self._check_device_owner()
         spec = self.spec
         candidates = []
         if spec.transfer.enabled:
@@ -458,6 +459,29 @@ class Investigation:
             constraints=[] if spec.objective is None else
             [c.describe() for c in spec.objective.constraints],
             failures=self._failure_summary())
+
+    def _check_device_owner(self) -> None:
+        """Refuse to measure a device-driving connector in child processes.
+
+        An accelerator belongs to one process: the parent that touched it
+        holds it, and the ``process`` and ``queue`` backends measure in
+        children that would fail or hang reaching it."""
+        backend = self._backend
+        isolated = (backend in ("process", "queue") if isinstance(backend, str)
+                    else isinstance(backend, (ProcessBackend, QueueBackend)))
+        if not isolated:
+            return
+        owners = [e.name for e in self.ds.actions.experiments
+                  if getattr(getattr(e, "connector", None), "needs_device",
+                             False)]
+        if owners:
+            label = backend if isinstance(backend, str) \
+                else type(backend).__name__
+            raise ValueError(
+                f"{', '.join(owners)} drive(s) the accelerator, which one "
+                f"process owns; the {label!r} backend measures in child "
+                f"processes that cannot reach it — use the 'serial' or "
+                f"'thread' backend")
 
     def _failure_summary(self) -> dict:
         """Per-phase failed-trial counts and charged provisioned cost for
@@ -506,6 +530,7 @@ class Investigation:
         before the first ask — the cross-session continuation path; reuse
         makes re-proposals free, so only new ground costs money.
         """
+        self._check_device_owner()
         spec = self.spec
         ds = self.ds
         members = (self._members if self._members is not None

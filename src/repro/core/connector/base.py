@@ -56,6 +56,11 @@ class ExperimentConnector(abc.ABC):
 
     name: str = "connector"
     version: str = "1"
+    #: True when the connector runs on the accelerator itself.  A device
+    #: belongs to one process, so such a connector must measure in the
+    #: process that owns it: ``Investigation`` refuses to hand it to the
+    #: ``process`` or ``queue`` execution backends.
+    needs_device: bool = False
 
     @property
     def parameterization(self) -> Mapping[str, Any]:

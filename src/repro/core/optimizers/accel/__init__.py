@@ -31,11 +31,13 @@ first accelerated scoring call.
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import warnings
 
 __all__ = ["BACKENDS", "jax_available", "pallas_available",
-           "resolve_backend", "gp_ei", "gp_pof", "tpe_scores", "bucket"]
+           "resolve_backend", "gp_ei", "gp_pof", "tpe_scores", "bucket",
+           "full_precision"]
 
 #: Every selectable ask backend, reference first.
 BACKENDS = ("numpy", "jax", "pallas")
@@ -47,6 +49,19 @@ def bucket(n: int, floor: int = 8) -> int:
     """Smallest power of two >= max(n, floor) — the shape key the jitted
     scorers pad to, so compiled programs are reused as history grows."""
     return max(floor, 1 << (max(n, 1) - 1).bit_length())
+
+
+def full_precision(fn):
+    """Trace ``fn`` with full-float32 matmuls.  A TPU runs an f32 matmul as
+    one bf16 pass by default, which would cost the Cholesky solve and the
+    distance expansions their parity with the float64 numpy reference;
+    elsewhere the setting changes nothing."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        import jax
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return traced
 
 
 def jax_available() -> bool:

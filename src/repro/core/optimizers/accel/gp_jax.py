@@ -54,7 +54,7 @@ try:  # pragma: no cover - exercised implicitly by backend gating
 except Exception:  # pragma: no cover - jax-less installs
     HAVE_JAX = False
 
-from . import bucket
+from . import bucket, full_precision
 
 __all__ = ["gp_ei", "gp_pof", "bucket"]
 
@@ -68,6 +68,7 @@ if HAVE_JAX:
         return rbf_matrix_jnp(A, B, inv2ls2)
 
     @functools.partial(jax.jit, static_argnames=("use_pallas",))
+    @full_precision
     def _gp_fit(Xh, yh, mh, inv2ls2, noise, use_pallas):
         # masked standardization (matches y.mean()/y.std() over real rows)
         nh = mh.sum()
@@ -106,6 +107,7 @@ if HAVE_JAX:
         return q
 
     @functools.partial(jax.jit, static_argnames=("use_pallas",))
+    @full_precision
     def _gp_ei(Linv, alpha, mu, sd, best, Xh, mh, Xc, inv2ls2, xi,
                use_pallas):
         Ks = _rbf(Xc, Xh, inv2ls2, use_pallas) * mh[None, :]
@@ -122,6 +124,7 @@ if HAVE_JAX:
         return imp * _jnorm.cdf(z) + std * _jnorm.pdf(z)
 
     @functools.partial(jax.jit, static_argnames=("use_pallas",))
+    @full_precision
     def _gp_pof(Linv, alpha, mu, sd, Xh, mh, Xc, inv2ls2, use_pallas):
         # Same cached-fit posterior as _gp_ei, squashed to P(feasible):
         # the GP regresses ±1 feasibility labels, so Φ(mean/std) is the

@@ -10,9 +10,10 @@ expansion with the exponential, so the MXU does the contraction and the
 
 Follows the repo kernel conventions (``src/repro/kernels/``): explicit
 BlockSpecs, fp32 accumulation via ``preferred_element_type``, lane padding
-to 128, ``interpret=True`` on CPU so the kernel is testable everywhere, and
-a pure-jnp oracle (:func:`rbf_matrix_jnp`) the pallas path is regression-
-gated against.  Import of pallas itself is deferred and failure-tolerant:
+to 128, the interpreter off the TPU (:func:`repro.kernels.ops.interpret_mode`)
+so the kernel is testable everywhere, and a pure-jnp oracle
+(:func:`rbf_matrix_jnp`) the pallas path is regression-gated against.
+Import of pallas itself is deferred and failure-tolerant:
 :func:`pallas_available` gates dispatch, and callers fall back to the jnp
 path on any platform where pallas is absent.
 """
@@ -51,9 +52,12 @@ def rbf_matrix_jnp(A: jax.Array, B: jax.Array, inv2ls2: jax.Array) -> jax.Array:
 def _rbf_block(s_ref, a_ref, b_ref, o_ref):
     a = a_ref[...].astype(jnp.float32)  # (block_m, d_pad)
     b = b_ref[...].astype(jnp.float32)  # (block_n, d_pad)
-    # zero-padded feature columns contribute 0 to every distance term
+    # zero-padded feature columns contribute 0 to every distance term;
+    # full-f32 MXU passes: the default single bf16 pass would cost the
+    # distance expansion its parity with the float64 reference
     d2 = ((a * a).sum(axis=1)[:, None] + (b * b).sum(axis=1)[None, :]
           - 2.0 * jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                                      precision=jax.lax.Precision.HIGHEST,
                                       preferred_element_type=jnp.float32))
     o_ref[...] = jnp.exp(-jnp.maximum(d2, 0.0) * s_ref[0, 0])
 
@@ -93,10 +97,9 @@ def _rbf_pallas_call(A, B, inv2ls2, *, block_m, block_n, interpret):
 def rbf_matrix_pallas(A: jax.Array, B: jax.Array, inv2ls2, *,
                       block_m: int = 256, block_n: int = 256,
                       interpret=None) -> jax.Array:
-    """Blocked pallas RBF Gram matrix; ``interpret=None`` auto-selects the
-    interpreter off-TPU (the repo-wide CPU-validation convention)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    """Blocked pallas RBF Gram matrix; ``interpret=None`` follows the
+    platform (:func:`repro.kernels.ops.interpret_mode`)."""
+    from ....kernels.ops import interpret_mode
     return _rbf_pallas_call(A, B, jnp.asarray(inv2ls2, jnp.float32),
                             block_m=block_m, block_n=block_n,
-                            interpret=bool(interpret))
+                            interpret=interpret_mode(interpret))

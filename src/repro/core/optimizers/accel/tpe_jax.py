@@ -33,7 +33,7 @@ try:  # pragma: no cover - exercised implicitly by backend gating
 except Exception:  # pragma: no cover - jax-less installs
     HAVE_JAX = False
 
-from . import bucket
+from . import bucket, full_precision
 
 __all__ = ["tpe_scores"]
 
@@ -59,6 +59,7 @@ if HAVE_JAX:
         return jnp.log(jnp.clip(pmf[i_cand], 1e-12, None))
 
     @jax.jit
+    @full_precision
     def _tpe_scores(g_num, g_m, b_num, b_m, c_num,
                     g_cat, b_cat, c_cat, k_masks, bw):
         score = jnp.zeros(c_num.shape[1] if c_num.shape[0]

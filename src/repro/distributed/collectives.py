@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .._compat.jaxshims import shard_map
-
 __all__ = ["quantize_int8", "dequantize_int8", "compressed_psum",
            "compressed_grad_sync"]
 
@@ -81,7 +79,7 @@ def compressed_grad_sync(grads, error_buffers, mesh: Mesh,
     in_specs = tuple(P() for _ in flat)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(in_specs, in_specs),
         out_specs=(in_specs, in_specs),
     )

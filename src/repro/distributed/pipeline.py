@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .._compat.jaxshims import pcast, shard_map
-
 __all__ = ["pipeline_forward", "bubble_fraction"]
 
 
@@ -52,7 +50,7 @@ def pipeline_forward(stage_fn: Callable, num_stages: int,
     S, M = num_stages, num_microbatches
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(stage_axis), P(None)),
         out_specs=P(None),
     )
@@ -68,8 +66,8 @@ def pipeline_forward(stage_fn: Callable, num_stages: int,
         buf = jnp.zeros_like(micro[0])          # activation entering this stage
         outs = jnp.zeros_like(micro)            # completed microbatches (stage S-1)
         # carries become stage-varying inside the loop; mark them upfront
-        buf = pcast(buf, (stage_axis,), to="varying")
-        outs = pcast(outs, (stage_axis,), to="varying")
+        buf = jax.lax.pcast(buf, (stage_axis,), to="varying")
+        outs = jax.lax.pcast(outs, (stage_axis,), to="varying")
 
         def tick(carry, t):
             buf, outs = carry

@@ -88,7 +88,7 @@ class DeploymentConfig:
         return ModelOptions(
             attn=AttnOptions(impl=self.attn_impl, q_chunk=self.attn_q_chunk,
                              kv_chunk=self.attn_kv_chunk,
-                             band_skip=self.band_skip, interpret=True,
+                             band_skip=self.band_skip,
                              shard_heads=self.attn_shard_heads,
                              shard_batch=tuple(self.batch_axes)),
             moe=MoEOptions(impl=self.moe_impl,

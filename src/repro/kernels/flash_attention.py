@@ -11,8 +11,8 @@ MXU-aligned (multiples of 128) matmul dims.
 GQA is handled in the K/V index map: query head ``h`` reads kv head
 ``h // (H/Hkv)`` — no repeated-KV materialization in HBM.
 
-Validated against ``ref.attention_ref`` in interpret mode (this CPU
-container); on real TPU hardware drop ``interpret=True``.
+Validated against ``ref.attention_ref``; off the TPU the kernel runs under
+the Pallas interpreter (see :func:`.ops.interpret_mode`).
 """
 
 from __future__ import annotations
@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ops import interpret_mode
 from .ref import NEG_INF
 
 __all__ = ["flash_attention"]
@@ -84,7 +85,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     q_offset: int = 0, block_q: int = 128,
-                    block_kv: int = 128, interpret: bool = True) -> jax.Array:
+                    block_kv: int = 128,
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (B,Sq,H,D); k/v: (B,Sk,Hkv,D).  Forward only (pair with the XLA
     custom-VJP path for training; the kernel targets serving/prefill)."""
     B, Sq, H, D = q.shape
@@ -134,7 +136,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),       # l
             pltpu.VMEM((block_q, D), jnp.float32),     # acc
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qr, kr, vr)
     out = out.reshape(B, H, Sq_p, D).transpose(0, 2, 1, 3)
     return out[:, :Sq]

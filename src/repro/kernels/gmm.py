@@ -17,11 +17,14 @@ capacity).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ops import interpret_mode
 
 __all__ = ["gmm_stacked_pallas", "gmm_pallas"]
 
@@ -45,7 +48,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_scr, *, nk: int):
 
 def gmm_stacked_pallas(xs: jax.Array, w: jax.Array, *, block_m: int = 128,
                        block_n: int = 128, block_k: int = 128,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """xs: (E, C, d); w: (E, d, f) -> (E, C, f)."""
     E, C, d = xs.shape
     _, _, f = w.shape
@@ -71,13 +74,14 @@ def gmm_stacked_pallas(xs: jax.Array, w: jax.Array, *, block_m: int = 128,
         out_specs=pl.BlockSpec((1, block_m, block_n), lambda e, m, n, k: (e, m, n)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, fp), xs.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(xs, w)
     return out[:, :C, :f]
 
 
 def gmm_pallas(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
-               block_m: int = 128, interpret: bool = True) -> jax.Array:
+               block_m: int = 128,
+               interpret: Optional[bool] = None) -> jax.Array:
     """Dynamic-group-size entry point: pads each group to the max group size
     into the stacked layout, runs the stacked kernel, then unpads.  (On TPU
     the capacity dispatch already produces the stacked layout directly —

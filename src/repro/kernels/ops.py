@@ -7,8 +7,11 @@ machinery):
 * ``ref``    — pure-jnp oracle (full materialization; tests/small shapes).
 * ``xla``    — memory-bounded lax.scan implementations (production fallback,
                and what the CPU-only dry-run lowers).
-* ``pallas`` — the TPU Pallas kernels with explicit VMEM BlockSpecs
-               (validated on CPU via interpret=True).
+* ``pallas`` — the TPU Pallas kernels with explicit VMEM BlockSpecs.
+
+Whether a Pallas kernel is compiled or interpreted is not an option: it
+follows the platform (:func:`interpret_mode`), so a kernel always compiles
+on a TPU and runs under the Pallas interpreter everywhere else.
 """
 
 from __future__ import annotations
@@ -21,14 +24,25 @@ import jax.numpy as jnp
 from . import ref as _ref
 from . import xla_attn as _xla_attn
 
-__all__ = ["attention", "decode_attention", "rglru", "gmm"]
+__all__ = ["attention", "decode_attention", "rglru", "gmm", "gmm_stacked",
+           "interpret_mode"]
+
+
+def interpret_mode(explicit: Optional[bool] = None) -> bool:
+    """Whether a Pallas kernel runs under the interpreter: on any backend
+    but the TPU, the only one that compiles them.  An explicit value (a
+    kernel's ``interpret=`` argument, which tests pin) wins."""
+    if explicit is not None:
+        return bool(explicit)
+    return jax.default_backend() != "tpu"
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
               q_offset: int = 0, impl: str = "xla",
               q_chunk: int = 512, kv_chunk: int = 512,
-              band_skip: bool = True, interpret: bool = True) -> jax.Array:
+              band_skip: bool = True,
+              interpret: Optional[bool] = None) -> jax.Array:
     """Full-sequence GQA attention.  q: (B,S,H,D); k/v: (B,S,Hkv,D)."""
     if impl == "ref":
         return _ref.attention_ref(q, k, v, causal=causal, window=window,
@@ -67,7 +81,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array, *,
 
 def rglru(x: jax.Array, log_a: jax.Array, gate_a: jax.Array, gate_x: jax.Array,
           h0: Optional[jax.Array] = None, *, impl: str = "xla",
-          block_d: int = 256, interpret: bool = True):
+          block_d: int = 256, interpret: Optional[bool] = None):
     """RG-LRU linear recurrence.  x/gates: (B,S,D); returns ((B,S,D), (B,D))."""
     if impl == "ref":
         return _ref.rglru_ref(x, log_a, gate_a, gate_x, h0)
@@ -104,7 +118,8 @@ def _rglru_assoc(x, log_a, gate_a, gate_x, h0=None, c: float = 8.0):
 
 
 def gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
-        impl: str = "xla", block_m: int = 128, interpret: bool = True) -> jax.Array:
+        impl: str = "xla", block_m: int = 128,
+        interpret: Optional[bool] = None) -> jax.Array:
     """Grouped matmul: x (T,d) rows grouped contiguously; w (E,d,f)."""
     if impl in ("ref", "xla"):
         return _ref.gmm_ref(x, w, group_sizes)  # XLA path shares the oracle
@@ -117,7 +132,7 @@ def gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
 
 def gmm_stacked(xs: jax.Array, w: jax.Array, *, impl: str = "xla",
                 block_m: int = 128, block_n: int = 128, block_k: int = 128,
-                interpret: bool = True) -> jax.Array:
+                interpret: Optional[bool] = None) -> jax.Array:
     """Static-capacity grouped matmul: xs (E,C,d) × w (E,d,f) -> (E,C,f).
     This is the production MoE expert-compute primitive on TPU."""
     if impl in ("ref", "xla"):

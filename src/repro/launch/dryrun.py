@@ -1,16 +1,16 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 # --- multi-pod dry-run driver -------------------------------------------------
 # Lowers + compiles every (architecture × input shape) cell for the production
 # mesh (16×16 single pod; 2×16×16 multi-pod), prints memory_analysis() and
 # cost_analysis(), and derives the three roofline terms per cell.
 #
-# The two lines above MUST stay the first two lines of this module: jax locks
-# the device count on first init, and only the dry-run gets 512 placeholder
-# devices (smoke tests and benches see 1 CPU device).
+#   PYTHONPATH=src python -m repro.launch.dryrun --arch xlstm-125m --mesh single
+#
+# Run as a script it asks XLA for 512 placeholder host devices, before any
+# backend is touched (jax locks the device count on first init).  A process
+# that only imports this module keeps its own devices.
 
 import argparse
+import os
 import json
 import time
 import traceback
@@ -212,4 +212,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

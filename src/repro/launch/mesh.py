@@ -4,6 +4,11 @@ Defined as FUNCTIONS (not module-level constants) so importing this module
 never touches jax device state — jax locks the device count on first init,
 and only the dry-run entry point is allowed to request 512 placeholder
 devices via XLA_FLAGS.
+
+Every mesh here has ``Auto`` axes: the model and step code shard by
+PartitionSpec annotations and ``with_sharding_constraint`` and leave the
+rest to the partitioner, which ``jax.make_mesh``'s default ``Explicit`` axes
+refuse.
 """
 
 from __future__ import annotations
@@ -11,6 +16,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "available_devices",
            "mesh_split_options", "parse_mesh_split"]
@@ -22,12 +28,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     leading 'pod' axis (DP across pods over DCN)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Arbitrary mesh (tests, elastic re-meshing, deployment search)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """Arbitrary mesh (tests, elastic re-meshing, deployment search) over
+    ``devices`` (default: all)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def available_devices() -> int:

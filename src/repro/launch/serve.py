@@ -1,8 +1,11 @@
 """Serving launcher: prefill + batched decode over the sharded serving path.
 
-CPU-scale demo of the production serving loop:
+The production serving loop:
   PYTHONPATH=src python -m repro.launch.serve --arch xlstm-125m --smoke \\
       --batch 4 --prompt-len 32 --gen 16
+
+Run as a script, it keeps compiled steps in the persistent compilation
+cache (:mod:`repro.launch.compile_cache`).
 """
 
 import argparse
@@ -20,6 +23,9 @@ from repro.serving.serve_step import make_decode_step, make_prefill_step
 
 
 def main(argv=None) -> dict:
+    """Prefill the prompts, then decode greedily.  Returns timings, the
+    prompts, the generated tokens (B, gen) and the logits behind each of
+    them (B, gen, V): prefill's last position, then one per decode step."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--smoke", action="store_true")
@@ -57,6 +63,7 @@ def main(argv=None) -> dict:
         t_prefill = time.time() - t0
 
         generated = [np.asarray(tok)]
+        step_logits = [logits]
         t0 = time.time()
         for i in range(args.gen - 1):
             step_batch = {"tokens": tok[:, None]} if cfg.uses_tokens else \
@@ -66,6 +73,7 @@ def main(argv=None) -> dict:
                                     args.prompt_len + i)
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             generated.append(np.asarray(tok))
+            step_logits.append(logits)
         t_decode = time.time() - t0
 
     out = np.stack(generated, axis=1)
@@ -75,8 +83,12 @@ def main(argv=None) -> dict:
           f"{tps:.1f} tok/s")
     print(f"[serve] sample continuation (seq 0): {out[0][:12].tolist()}")
     return {"prefill_ms": t_prefill * 1e3, "tokens_per_s": tps,
-            "tokens": out}
+            "prompts": prompts, "tokens": out,
+            "logits": np.stack([np.asarray(x, np.float32) for x in step_logits],
+                               axis=1)}
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,9 +1,12 @@
 """End-to-end training launcher: data → sharded train step → checkpoints,
 with restart-after-failure and elastic re-meshing.
 
-Usage (CPU-scale):
+Usage:
   PYTHONPATH=src python -m repro.launch.train --arch xlstm-125m --smoke \\
       --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+
+Run as a script, it keeps compiled steps in the persistent compilation
+cache (:mod:`repro.launch.compile_cache`).
 
 The launcher is deliberately structured the way a 1000-node job would be:
   1. build/restore: if the checkpoint dir has a latest step, resume from it
@@ -11,16 +14,8 @@ The launcher is deliberately structured the way a 1000-node job would be:
      re-mesh, since checkpoints are mesh-independent);
   2. deterministic data cursor = global step (stream is seekable, so resume
      needs no data-state persistence);
-  3. checkpoint every N steps (async), retain K;
-  4. XLA latency-hiding flags are set for collective/compute overlap.
+  3. checkpoint every N steps (async), retain K.
 """
-
-import os
-
-# latency-hiding scheduler: overlap collectives with compute (harmless on CPU)
-os.environ.setdefault(
-    "XLA_FLAGS",
-    "--xla_cpu_enable_fast_math=false")
 
 import argparse
 import time
@@ -40,11 +35,13 @@ from repro.training.optimizer import AdamWConfig
 from repro.training.train_step import init_train_state, make_train_step
 
 
-def build(args):
-    n_dev = len(jax.devices())
+def build(args, devices=None):
+    """Mesh, model and jitted step.  ``devices`` (default: all) are the
+    devices the ``data × model`` mesh spans."""
+    devices = list(jax.devices() if devices is None else devices)
     model_axis = args.model_axis if args.model_axis else 1
-    data_axis = n_dev // model_axis
-    mesh = make_mesh((data_axis, model_axis), ("data", "model"))
+    data_axis = len(devices) // model_axis
+    mesh = make_mesh((data_axis, model_axis), ("data", "model"), devices)
     cfg = get_config(args.arch, smoke=args.smoke)
     deployment = default_deployment(cfg, mesh, shape_kind="train",
                                     global_batch=args.batch, seq_len=args.seq)
@@ -58,7 +55,20 @@ def build(args):
     return mesh, cfg, model, deployment, step_fn, state_specs, bspecs
 
 
-def main(argv=None) -> dict:
+def initial_state(model, args) -> dict:
+    """The fresh (un-restored) training state: a function of the run's
+    arguments only, so a caller can rebuild the step-0 parameters."""
+    return init_train_state(model, jax.random.PRNGKey(args.steps))
+
+
+def data_pipeline(cfg, args) -> TokenPipeline:
+    """The run's seekable token stream; ``batch_at(step)`` is its batch."""
+    return TokenPipeline(DataConfig(vocab_size=cfg.vocab_size,
+                                    seq_len=args.seq,
+                                    global_batch=args.batch, seed=13))
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--smoke", action="store_true")
@@ -75,9 +85,16 @@ def main(argv=None) -> dict:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--stop-after", type=int, default=0,
                     help="simulate failure: exit after N steps")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    mesh, cfg, model, deployment, step_fn, state_specs, bspecs = build(args)
+
+def main(argv=None, devices=None) -> dict:
+    """Train; returns the run's summary, its per-step losses, gradient norms
+    and wall times (step 0's includes compiling), and the final (sharded)
+    state."""
+    args = parse_args(argv)
+    mesh, cfg, model, deployment, step_fn, state_specs, bspecs = build(
+        args, devices)
     with mesh:
         mgr = None
         start_step = 0
@@ -94,26 +111,35 @@ def main(argv=None) -> dict:
                 start_step = int(manifest["step"])
                 print(f"[train] restored checkpoint at step {start_step}")
         if state is None:
-            state = init_train_state(model, jax.random.PRNGKey(args.steps))
-            state = jax.device_put(state, named_sharding_tree(state_specs, mesh))
+            state = jax.device_put(initial_state(model, args),
+                                   named_sharding_tree(state_specs, mesh))
 
-        data = TokenPipeline(DataConfig(vocab_size=cfg.vocab_size,
-                                        seq_len=args.seq,
-                                        global_batch=args.batch, seed=13))
+        data = data_pipeline(cfg, args)
         data.start(cursor=start_step)
 
-        losses = []
+        losses, grad_norms, step_times = [], [], []
+
+        def summary() -> dict:
+            return {"first_loss": losses[0] if losses else None,
+                    "last_loss": losses[-1] if losses else None,
+                    "steps_run": len(losses), "resumed_from": start_step,
+                    "losses": losses, "grad_norms": grad_norms,
+                    "step_times": step_times, "state": state}
+
         t0 = time.time()
         for step in range(start_step, args.steps):
             cursor, batch = next(data)
             assert cursor == step, f"data cursor {cursor} != step {step}"
+            t_step = time.perf_counter()
             state, metrics = step_fn(state, batch)
             loss = float(metrics["loss"])
+            step_times.append(time.perf_counter() - t_step)
             losses.append(loss)
+            grad_norms.append(float(metrics["grad_norm"]))
             if step % args.log_every == 0 or step == args.steps - 1:
                 dt = time.time() - t0
                 print(f"[train] step {step:5d} loss {loss:.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
+                      f"gnorm {grad_norms[-1]:.3f} "
                       f"lr {float(metrics['lr']):.2e} ({dt:.1f}s)")
             if mgr is not None and mgr.should_save(step + 1):
                 mgr.save(step + 1, state, {"loss": loss})
@@ -127,8 +153,7 @@ def main(argv=None) -> dict:
                     mgr.wait()
                 print(f"[train] simulated failure after {args.stop_after} steps")
                 data.stop()
-                return {"first_loss": losses[0], "last_loss": losses[-1],
-                        "steps_run": len(losses), "resumed_from": start_step}
+                return summary()
         data.stop()
         # `losses` is empty when resuming a run that already completed
         # (start_step == steps): nothing ran, nothing new to checkpoint.
@@ -136,11 +161,12 @@ def main(argv=None) -> dict:
             mgr.save(start_step + len(losses), state, {"loss": losses[-1]},
                      async_=False)
             mgr.wait()
-    return {"first_loss": losses[0] if losses else None,
-            "last_loss": losses[-1] if losses else None,
-            "steps_run": len(losses), "resumed_from": start_step}
+    return summary()
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out = main()
+    del out["state"]
     print(f"[train] done: {out}")

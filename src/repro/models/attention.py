@@ -29,7 +29,6 @@ class AttnOptions:
     q_chunk: int = 512
     kv_chunk: int = 512
     band_skip: bool = True
-    interpret: bool = True    # pallas interpret mode (CPU container)
     # shard query heads over this mesh axis inside attention even when the
     # head count doesn't divide it (GSPMD pads) — rescues architectures like
     # llama4 (40 heads vs 16-way TP) from replicated attention compute
@@ -77,7 +76,7 @@ def attention_apply(params, x: jax.Array, cfg: ModelConfig, positions: jax.Array
     out = ops.attention(
         q, k, v, causal=cfg.causal, window=window, impl=opts.impl,
         q_chunk=opts.q_chunk, kv_chunk=opts.kv_chunk,
-        band_skip=opts.band_skip, interpret=opts.interpret,
+        band_skip=opts.band_skip,
     )
     out = _constrain_heads(out, opts)
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
@@ -124,8 +123,7 @@ def prefill_kv_cache(params, x: jax.Array, cfg: ModelConfig, positions: jax.Arra
     q, k, v = _project_qkv(params, x, cfg, positions)
     out = ops.attention(q, k, v, causal=cfg.causal, window=window,
                         impl=opts.impl, q_chunk=opts.q_chunk,
-                        kv_chunk=opts.kv_chunk, band_skip=opts.band_skip,
-                        interpret=opts.interpret)
+                        kv_chunk=opts.kv_chunk, band_skip=opts.band_skip)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     S = x.shape[1]
     c = min(window, capacity) if window is not None else capacity

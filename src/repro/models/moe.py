@@ -35,7 +35,6 @@ class MoEOptions:
     capacity_factor: float = 1.25
     min_capacity: int = 4       # capacity floor (matters for tiny token counts)
     gmm_impl: str = "xla"       # xla | pallas (inner grouped-matmul kernel)
-    interpret: bool = True
 
 
 def moe_defs(cfg: ModelConfig) -> dict:
@@ -100,12 +99,11 @@ def _expert_ffn(params, xs: jax.Array, cdt, opts: "MoEOptions" = None) -> jax.Ar
     """xs: (E, C, d) -> (E, C, d) through each expert's gated MLP.
     Uses the stacked grouped-matmul primitive (Pallas kernel on TPU)."""
     gi = opts.gmm_impl if opts is not None else "xla"
-    interp = opts.interpret if opts is not None else True
-    g = ops.gmm_stacked(xs, params["w_gate"], impl=gi, interpret=interp)
-    u = ops.gmm_stacked(xs, params["w_up"], impl=gi, interpret=interp)
+    g = ops.gmm_stacked(xs, params["w_gate"], impl=gi)
+    u = ops.gmm_stacked(xs, params["w_up"], impl=gi)
     return ops.gmm_stacked((jax.nn.silu(g.astype(jnp.float32)) *
                             u.astype(jnp.float32)).astype(cdt),
-                           params["w_down"], impl=gi, interpret=interp)
+                           params["w_down"], impl=gi)
 
 
 def _dense_moe(params, xt, experts, weights, cfg):
@@ -166,9 +164,9 @@ def _gmm_moe(params, xt, experts, weights, cfg, opts):
 
     xs = xt[src]                                          # (T·k, d) sorted
     gi = opts.gmm_impl
-    g = ops.gmm(xs, params["w_gate"], group_sizes, impl=gi, interpret=opts.interpret)
-    u = ops.gmm(xs, params["w_up"], group_sizes, impl=gi, interpret=opts.interpret)
+    g = ops.gmm(xs, params["w_gate"], group_sizes, impl=gi)
+    u = ops.gmm(xs, params["w_up"], group_sizes, impl=gi)
     h = (jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)).astype(cdt)
-    y = ops.gmm(h, params["w_down"], group_sizes, impl=gi, interpret=opts.interpret)
+    y = ops.gmm(h, params["w_down"], group_sizes, impl=gi)
     y = y * ws[:, None].astype(cdt)
     return jnp.zeros((T, d), cdt).at[src].add(y)

@@ -27,7 +27,6 @@ __all__ = ["rglru_defs", "rglru_apply", "rglru_decode", "init_rglru_state",
 class RGLRUOptions:
     impl: str = "xla"        # ref | xla | pallas
     block_d: int = 256
-    interpret: bool = True
 
 
 def rglru_defs(cfg: ModelConfig) -> dict:
@@ -71,8 +70,7 @@ def _mix(params, u: jax.Array, opts: RGLRUOptions, h0, conv_state):
     gate_a = jnp.einsum("bsr,rq->bsq", conv_out, params["w_gate_a"].astype(u.dtype))
     gate_x = jnp.einsum("bsr,rq->bsq", conv_out, params["w_gate_x"].astype(u.dtype))
     h, h_last = ops.rglru(conv_out, params["log_lambda"], gate_a, gate_x, h0,
-                          impl=opts.impl, block_d=opts.block_d,
-                          interpret=opts.interpret)
+                          impl=opts.impl, block_d=opts.block_d)
     return h, h_last, new_conv
 
 

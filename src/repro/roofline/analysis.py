@@ -125,23 +125,11 @@ def roofline_terms(hlo_flops: float, hlo_bytes: float,
     return compute_s, memory_s, collective_s
 
 
-def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across jaxlib versions.
-
-    Older jaxlib returns a one-element list of dicts (one per partition),
-    newer jaxlib returns the dict directly; either way callers get a dict.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def analyze_compiled(compiled, arch: str, shape: str, mesh_desc: str,
                      chips: int, mesh_groups: Dict[str, int],
                      model_flops: float, hw: HWSpec = HW_V5E,
                      hlo_text: Optional[str] = None) -> RooflineReport:
-    cost = xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     raw_flops = float(cost.get("flops", 0.0))
     raw_bytes = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

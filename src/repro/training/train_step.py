@@ -4,8 +4,7 @@ AdamW update, built for pjit with explicit in/out shardings.
 Microbatch gradient accumulation runs as ``lax.scan`` over microbatches —
 with batch sharded over DP axes, XLA schedules each microbatch's gradient
 reduce-scatter to overlap the next microbatch's compute (the standard
-latency-hiding structure; enabled further by the scheduler flags set in
-``launch/train.py``).
+latency-hiding structure).
 """
 
 from __future__ import annotations

@@ -171,6 +171,7 @@ class WalltimeConnector(ExperimentConnector):
 
     name = "walltime"
     version = "1"
+    needs_device = True
 
     def __init__(self, arch: str, repeats: int = 3, compute_dtype="float32",
                  arch_scale: float = 1.0, clock: Clock = SYSTEM_CLOCK):

@@ -19,9 +19,11 @@ sibling:
   (smoke-scaled config) with the configuration's kernel variant and compute
   dtype, compiles the jitted train/serve step, and times it on the local
   devices.  A configuration whose mesh split wants more chips than the host
-  has — or whose kernel fails to compile — is non-deployable here even when
-  the cost model likes it, which is exactly the disagreement tiering exists
-  to surface.
+  has — or that runs out of device memory at compile or run time — is
+  non-deployable here even when the cost model likes it, which is exactly
+  the disagreement tiering exists to surface.  Any other failure is a fault
+  of the program or the device, not a property of the configuration: it
+  propagates and stops the investigation rather than posing as a result.
 
 Identity: the per-member knobs (arch, kind, seq_len, devices, hw) live in
 the connector *parameterization*, not in Ω — so two family members with
@@ -59,6 +61,13 @@ def resolve_hw(hw: Union[str, HWSpec]) -> HWSpec:
         raise ValueError(f"unknown hardware {hw!r} "
                          f"(known: {sorted(_HW_BY_NAME)})")
     return _HW_BY_NAME[hw]
+
+
+def _out_of_memory(err: Exception) -> bool:
+    """True for the device running out of memory — XLA's RESOURCE_EXHAUSTED
+    status, raised at compile time (the program does not fit) or at run
+    time (an allocation failed)."""
+    return "RESOURCE_EXHAUSTED" in str(err)
 
 
 def _decode(configuration: Configuration, devices: int) -> dict:
@@ -139,6 +148,7 @@ class LLMWalltimeConnector(ExperimentConnector):
 
     name = "llm-walltime"
     version = "1"
+    needs_device = True
 
     def __init__(self, arch: str, seq_len: int, devices: int = 1,
                  kind: str = "train", repeats: int = 3, smoke: bool = True,
@@ -188,7 +198,7 @@ class LLMWalltimeConnector(ExperimentConnector):
         chunk = max(16, min(self.seq_len, 128))
         model = LMModel(cfg, ModelOptions(
             attn=AttnOptions(impl=KERNEL_IMPLS[decoded["kernel"]],
-                             q_chunk=chunk, kv_chunk=chunk, interpret=True),
+                             q_chunk=chunk, kv_chunk=chunk),
             policy=DTypePolicy(param_dtype=jnp.float32,
                                compute_dtype=compute)))
         batch, seq = decoded["batch"], self.seq_len
@@ -221,6 +231,8 @@ class LLMWalltimeConnector(ExperimentConnector):
         try:
             jax.block_until_ready(step(params, b))  # compile
         except Exception as e:
+            if not _out_of_memory(e):
+                raise
             raise MeasurementError(f"non-deployable: {type(e).__name__}: {e}")
         return Deployment(
             ident=f"llm-walltime-{configuration.digest[:12]}",
@@ -237,6 +249,8 @@ class LLMWalltimeConnector(ExperimentConnector):
                 jax.block_until_ready(step(params, b))
                 times.append(self.clock.monotonic() - t0)
         except Exception as e:
+            if not _out_of_memory(e):
+                raise
             raise MeasurementError(f"non-deployable: {e}")
         return min(times), deployment.meta
 

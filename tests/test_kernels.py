@@ -233,3 +233,27 @@ def test_moe_capacity_matches_dense_when_no_drops():
     np.testing.assert_allclose(np.asarray(y_gmm), np.asarray(y_dense),
                                rtol=2e-4, atol=2e-4)
     assert np.isclose(float(aux1), float(aux2))
+
+
+# ---------------------------------------------------------------- interpret
+
+
+def test_pallas_interpret_mode_follows_the_platform():
+    """Compiled on a TPU, interpreted everywhere else; an explicit argument
+    still wins."""
+    assert ops.interpret_mode() is (jax.default_backend() != "tpu")
+    assert ops.interpret_mode() is True  # this suite runs on the CPU
+    assert ops.interpret_mode(False) is False
+
+
+@pytest.mark.parametrize("options", ["attention.AttnOptions", "moe.MoEOptions",
+                                     "rglru.RGLRUOptions"])
+def test_model_options_carry_no_interpret_switch(options):
+    """Whether a kernel is interpreted is the platform's call, never a
+    deployment option that could leave the interpreter on over a chip."""
+    import dataclasses
+    import importlib
+
+    module, name = options.split(".")
+    cls = getattr(importlib.import_module(f"repro.models.{module}"), name)
+    assert "interpret" not in {f.name for f in dataclasses.fields(cls)}

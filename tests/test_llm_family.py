@@ -11,6 +11,7 @@ round-trip with the new ``meta``/``predict_remaining`` fields, and the
 end-to-end sibling transfer with the step-⑧ predict-remaining sweep.
 """
 
+import dataclasses
 import math
 
 import pytest
@@ -130,6 +131,59 @@ def test_walltime_more_devices_than_host_is_non_deployable():
     conn = LLMWalltimeConnector(ARCH, seq_len=32, devices=4096)
     with pytest.raises(MeasurementError, match="non-deployable"):
         conn.provision(a_config(mesh="1x4096"))
+
+
+@pytest.mark.parametrize("message, terminal", [
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm", True),
+    ("INTERNAL: Mosaic failed to compile TPU kernel", False),
+])
+def test_walltime_only_out_of_memory_is_non_deployable(monkeypatch, message,
+                                                       terminal):
+    """A configuration that does not fit the device is a search result; any
+    other failure is the program's or the device's, and must surface."""
+    from repro.models.model import LMModel
+
+    def fail(self, params, batch):
+        raise RuntimeError(message)
+
+    monkeypatch.setattr(LMModel, "loss", fail)
+    conn = LLMWalltimeConnector(ARCH, seq_len=32)
+    config = a_config(mesh="1x1", batch=1)
+    if terminal:
+        with pytest.raises(MeasurementError, match="non-deployable"):
+            conn.provision(config)
+    else:
+        with pytest.raises(RuntimeError, match="Mosaic"):
+            conn.provision(config)
+
+
+@pytest.mark.parametrize("backend", ["process", "queue"])
+def test_walltime_member_refuses_child_process_backends(family, backend):
+    """The chip belongs to the process that touched it: a walltime member
+    may not be measured by child workers."""
+    spec = family.investigation_spec(seq_len=32, devices=1, tier="walltime",
+                                     max_trials=1)
+    spec = dataclasses.replace(
+        spec, execution=dataclasses.replace(spec.execution, backend=backend))
+    inv = Investigation(spec)
+    with pytest.raises(ValueError, match="accelerator"):
+        inv.plan()
+    with pytest.raises(ValueError, match="accelerator"):
+        inv.run()
+
+
+def test_device_free_tiers_keep_every_backend(family):
+    dryrun = family.investigation_spec(seq_len=32, devices=1, max_trials=1)
+    dryrun = dataclasses.replace(
+        dryrun, execution=dataclasses.replace(dryrun.execution,
+                                              backend="process"))
+    assert Investigation(dryrun).plan().backend == "process"
+    walltime = family.investigation_spec(seq_len=32, devices=1,
+                                         tier="walltime", max_trials=1)
+    walltime = dataclasses.replace(
+        walltime, execution=dataclasses.replace(walltime.execution,
+                                                backend="serial"))
+    assert Investigation(walltime).plan().backend == "serial"
 
 
 def test_walltime_parse_survives_zero_elapsed_time():

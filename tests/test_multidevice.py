@@ -94,7 +94,7 @@ def test_compressed_psum_across_real_pod_axis():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro._compat.jaxshims import shard_map
+        from jax import shard_map
         from repro.distributed.collectives import compressed_psum
 
         mesh = jax.make_mesh((8,), ("pod",))

@@ -5,8 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.roofline.analysis import (_ring_factor, roofline_terms,
-                                     xla_cost_analysis)
+from repro.roofline.analysis import _ring_factor, roofline_terms
 from repro.roofline.hlo_parse import analyze_hlo
 from repro.roofline.hw import HW_V5E
 
@@ -27,7 +26,7 @@ def test_xla_cost_analysis_counts_scan_once():
     x = jnp.zeros((64, 64))
     ws = jnp.zeros((8, 64, 64))
     compiled = _compile(scanned, x, ws)
-    flops_xla = xla_cost_analysis(compiled).get("flops", 0.0)
+    flops_xla = compiled.cost_analysis().get("flops", 0.0)
     one_matmul = 2 * 64 * 64 * 64
     assert flops_xla == pytest.approx(one_matmul, rel=0.01)  # NOT ×8
 

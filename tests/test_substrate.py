@@ -231,7 +231,7 @@ def test_quantize_roundtrip_exact_for_representable():
 def test_compressed_psum_error_feedback_converges():
     """Mean of a constant gradient over repeated steps: error feedback makes
     the time-averaged compressed mean converge to the true mean."""
-    from repro._compat.jaxshims import shard_map
+    from jax import shard_map
     from repro.distributed.collectives import compressed_psum
 
     mesh = jax.make_mesh((1,), ("pod",))
