@@ -4,6 +4,7 @@
 
     python -m repro.core.api run spec.json [--store PATH] [--dry-run]
                                            [--resume] [--out RESULT.json]
+                                           [--profile DIR]
     python -m repro.core.api validate spec.json
     python -m repro.core.api catalog --store PATH
     python -m repro.core.api frontier --store PATH --space ID \
@@ -24,6 +25,12 @@ configurations through the spec's first experiment/connector and captures
 the actuation trace (phase outcomes, durations, retries, properties) to a
 JSONL file replayable via the ``trace-replay`` factory — pay for a sweep
 once, replay it forever.
+
+``run --profile DIR`` runs the investigation inside a JAX profiler session
+(host events on, the Python tracer off), writes the trace under
+``DIR/plugins/profile/`` and the program's spans to ``DIR/spans.jsonl``
+(:mod:`repro.core.tracing`), and prints one row per span name — count,
+total, self, mean and p95 — and the counters.
 
 Run as a script, ``run`` keeps the programs its connectors compile in the
 persistent compilation cache (:mod:`repro.launch.compile_cache`).
@@ -56,7 +63,10 @@ def _cmd_run(args) -> int:
     print(plan.describe())
     if args.dry_run:
         return 0
-    result = inv.run(resume=args.resume)
+    if args.profile:
+        result = _profiled(args.profile, lambda: inv.run(resume=args.resume))
+    else:
+        result = inv.run(resume=args.resume)
     summary = result.summary()
     print(f"\ninvestigation {spec.name!r} finished: "
           f"{summary['trials']} trials, "
@@ -85,6 +95,43 @@ def _cmd_run(args) -> int:
             json.dump(summary, f, indent=2, sort_keys=True, default=str)
         print(f"wrote {args.out}")
     return 0
+
+
+def _profiled(directory: str, run):
+    """``run()`` inside a JAX profiler session that records host events
+    without the Python tracer (which times every Python call and would
+    inflate host spans unevenly); then the trace and ``spans.jsonl`` in
+    ``directory``, and the spans' table and counters on stdout."""
+    import os
+
+    import jax
+
+    from .. import tracing
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    os.makedirs(directory, exist_ok=True)
+    tracing.reset()
+    jax.profiler.start_trace(directory, profiler_options=options)
+    try:
+        result = run()
+    finally:
+        jax.profiler.stop_trace()
+    path = os.path.join(directory, "spans.jsonl")
+    n = tracing.write_jsonl(path)
+    print(f"\n{'span':<20} {'count':>7} {'total_ms':>11} {'self_ms':>11} "
+          f"{'mean_ms':>9} {'p95_ms':>9}")
+    for name, k, total, own, mean, p95 in tracing.summary(tracing.spans()):
+        print(f"{name:<20} {k:>7} {1e3 * total:>11.3f} {1e3 * own:>11.3f} "
+              f"{1e3 * mean:>9.3f} {1e3 * p95:>9.3f}")
+    for name, value in sorted(tracing.counters().items()):
+        print(f"counter {name} = {value}")
+    dropped = tracing.dropped()
+    print(f"wrote {n} spans to {path}"
+          + (f" ({dropped} dropped: buffer full)" if dropped else "")
+          + f"; profiler trace under {directory}")
+    return result
 
 
 def _cmd_validate(args) -> int:
@@ -171,6 +218,10 @@ def main(argv=None) -> int:
                             "into each member's history before the first ask")
     p_run.add_argument("--out", default=None,
                        help="write the result summary JSON here")
+    p_run.add_argument("--profile", default=None, metavar="DIR",
+                       help="run inside a JAX profiler session; write the "
+                            "trace and the program's spans (spans.jsonl) "
+                            "to DIR and print a table of them")
     p_run.set_defaults(fn=_cmd_run)
 
     p_val = sub.add_parser("validate",

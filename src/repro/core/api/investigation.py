@@ -37,6 +37,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from .. import tracing
 from ..campaign import MemberResult, _drive_fleet, _Member
 from ..clustering import select_indices
 from ..discovery import DiscoverySpace
@@ -541,9 +542,10 @@ class Investigation:
         if self._manage_history:
             warm = resume or spec.warm_start
             if warm:
-                for m in members:
-                    m.adapter.record_watermark = 0
-                    m.foreign_told += m.adapter.sync_foreign()
+                with tracing.span("engine.resume"):
+                    for m in members:
+                        m.adapter.record_watermark = 0
+                        m.foreign_told += m.adapter.sync_foreign()
             if spec.transfer.enabled:
                 transfer_report = self._apply_transfer(members)
             # fleet sharing starts at "now": pre-run records are covered by
@@ -606,18 +608,22 @@ class Investigation:
         try:
             while not rule.stop and member.own_told < max_trials:
                 n = min(batch_size, max_trials - member.own_told)
-                batch = optimizer.ask(adapter, rng, n=n)
-                if not as_scored(batch):
-                    member.exhausted = True
-                    break
-                before = len(adapter.trials)
-                adapter.evaluate_batch(batch, workers=workers,
-                                       executor=pool, backend=engine)
-                told = adapter.trials[before:]
-                member.own_told += len(told)
-                for t in told:
-                    rule.observe(t.value, t.feasible)
-                    events.append((member.label, t))
+                # a step's trial id is the seq of the first trial it tells
+                tracing.trial(len(adapter.trials))
+                with tracing.span("trial"):
+                    with tracing.span("ask"):
+                        batch = optimizer.ask(adapter, rng, n=n)
+                    if not as_scored(batch):
+                        member.exhausted = True
+                        break
+                    before = len(adapter.trials)
+                    adapter.evaluate_batch(batch, workers=workers,
+                                           executor=pool, backend=engine)
+                    told = adapter.trials[before:]
+                    member.own_told += len(told)
+                    for t in told:
+                        rule.observe(t.value, t.feasible)
+                        events.append((member.label, t))
         finally:
             if pool is not None:
                 pool.shutdown(wait=False)

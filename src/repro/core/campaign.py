@@ -54,6 +54,7 @@ from typing import List, Optional, Sequence, Union
 
 import numpy as np
 
+from . import tracing
 from .discovery import DiscoverySpace
 from .execution import ExecutionBackend, WorkItem
 from .optimizers.base import (FOREIGN_ACTION, WARM_ACTION, Optimizer,
@@ -181,9 +182,10 @@ def _absorb(ds: DiscoverySpace, completed, state: _RunState) -> bool:
             continue
         result = ds.record_result(config, digest, res.action, res.error,
                                   member.adapter.operation_id)
-        trial = member.adapter.tell_result(result)
-        member.own_told += 1
-        member.rule.observe(trial.value, trial.feasible)
+        with tracing.span("tell"):
+            trial = member.adapter.tell_result(result)
+            member.own_told += 1
+            member.rule.observe(trial.value, trial.feasible)
         state.events.append((member.label, trial))
     return bool(completed)
 
@@ -229,30 +231,37 @@ def _drive_fleet(ds: DiscoverySpace, members: Sequence[_Member],
                         break
                     if not member.wants_more(max_trials):
                         continue
-                    if share_history:
-                        member.foreign_told += member.adapter.sync_foreign()
-                    batch = as_scored(member.optimizer.ask(
-                        member.adapter, member.rng, n=1))
-                    if not batch:
-                        member.exhausted = True
-                        continue
-                    cand = batch[0]
-                    digest = ds.store.put_configuration(cand.configuration)
-                    member.adapter.pending.add(digest)
-                    engine.submit(WorkItem(
-                        cand.configuration, digest, state.tag,
-                        priority=(0.0 if cand.score is None
-                                  else float(cand.score))))
-                    state.inflight[state.tag] = (
-                        member, cand.configuration, digest)
-                    member.inflight += 1
-                    state.tag += 1
-                    submitted = True
-                    # drain anything already finished before the next
-                    # member's ask: synchronous backends hand every ask
-                    # the complete fleet history
-                    if _absorb(ds, engine.poll(), state):
-                        pause = 0.0005
+                    # a fleet trial's id is its work item's tag
+                    tracing.trial(state.tag)
+                    with tracing.span("trial"):
+                        if share_history:
+                            member.foreign_told += \
+                                member.adapter.sync_foreign()
+                        with tracing.span("ask"):
+                            batch = as_scored(member.optimizer.ask(
+                                member.adapter, member.rng, n=1))
+                        if not batch:
+                            member.exhausted = True
+                            continue
+                        cand = batch[0]
+                        with tracing.span("store.intern"):
+                            digest = ds.store.put_configuration(
+                                cand.configuration)
+                        member.adapter.pending.add(digest)
+                        engine.submit(WorkItem(
+                            cand.configuration, digest, state.tag,
+                            priority=(0.0 if cand.score is None
+                                      else float(cand.score))))
+                        state.inflight[state.tag] = (
+                            member, cand.configuration, digest)
+                        member.inflight += 1
+                        state.tag += 1
+                        submitted = True
+                        # drain anything already finished before the next
+                        # member's ask: synchronous backends hand every ask
+                        # the complete fleet history
+                        if _absorb(ds, engine.poll(), state):
+                            pause = 0.0005
             if not state.inflight and not submitted:
                 break
             if _absorb(ds, engine.poll(), state) or submitted:

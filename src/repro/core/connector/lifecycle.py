@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Any, Mapping, Optional, Sequence
 
+from .. import tracing
 from ..actions import (Experiment, FailureRecord, MeasurementError,
                        ProvisioningError)
 from ..clock import SYSTEM_CLOCK, Clock
@@ -97,7 +98,8 @@ class LifecycleExperiment(Experiment):
             tries += 1
             t0 = clock.time()
             try:
-                deployment = self.connector.provision(configuration)
+                with tracing.span("connector.provision"):
+                    deployment = self.connector.provision(configuration)
                 bill(t0)  # the successful attempt's window is provisioned time
             except ProvisioningError as err:
                 bill(t0)  # partially provisioned time is still billed
@@ -119,9 +121,11 @@ class LifecycleExperiment(Experiment):
         t0 = clock.time()
         phase = "run"
         try:
-            raw = self._run(deployment, digest)
+            with tracing.span("connector.run"):
+                raw = self._run(deployment, digest)
             phase = "parse"
-            props = dict(self.connector.parse(raw))
+            with tracing.span("connector.parse"):
+                props = dict(self.connector.parse(raw))
         except ProvisioningError as err:
             self._teardown(deployment)
             bill(t0)
@@ -167,6 +171,7 @@ class LifecycleExperiment(Experiment):
             return
         deployment.torn_down = True
         try:
-            self.connector.teardown(deployment)
+            with tracing.span("connector.teardown"):
+                self.connector.teardown(deployment)
         except Exception:
             pass

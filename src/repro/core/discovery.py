@@ -39,6 +39,7 @@ from typing import Iterator, Mapping, Optional, Sequence, Union
 
 import numpy as np
 
+from . import tracing
 from .actions import ActionSpace, Experiment, MeasurementError, SurrogateExperiment
 from .clock import Clock
 from .entities import Configuration, Sample, content_hash
@@ -251,7 +252,8 @@ class DiscoverySpace:
             self.space.validate(config)
         self._maybe_sweep_claims()
         # one interning transaction/round-trip for the whole batch
-        digests = self.store.put_configurations(configs)
+        with tracing.span("store.intern"):
+            digests = self.store.put_configurations(configs)
 
         # Duplicates measure once: the first slot of each digest does the
         # experiment work, later slots transparently reuse (§III-C5).
@@ -292,12 +294,15 @@ class DiscoverySpace:
             events.append((digest, action))
             recorded.append(digest)
             results.append(BatchResult(config, None, action, err))
-        self.store.append_records(self.space_id, operation_id, events)
+        with tracing.span("store.record"):
+            self.store.append_records(self.space_id, operation_id, events)
         if crash is not None:
             raise crash
-        for result, digest in zip(results, recorded):
-            if result.error is None:
-                result.sample = self._reconstruct(digest, result.configuration)
+        with tracing.span("store.read"):
+            for result, digest in zip(results, recorded):
+                if result.error is None:
+                    result.sample = self._reconstruct(digest,
+                                                      result.configuration)
         return results
 
     def record_result(self, configuration: Configuration, digest: str,
@@ -311,10 +316,13 @@ class DiscoverySpace:
         its backend reports completion — so events land in completion order,
         which *is* the submission order when ``max_inflight=1``.
         """
-        self.store.append_record(self.space_id, operation_id, digest, action)
+        with tracing.span("store.record"):
+            self.store.append_record(self.space_id, operation_id, digest,
+                                     action)
         result = BatchResult(configuration, None, action, error)
         if error is None:
-            result.sample = self._reconstruct(digest, configuration)
+            with tracing.span("store.read"):
+                result.sample = self._reconstruct(digest, configuration)
         return result
 
     # -------------------------------------------------------------------- read

@@ -27,6 +27,7 @@ import time
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence
 
+from .. import tracing
 from ..actions import FailureRecord, MeasurementError
 from ..clock import Clock, SYSTEM_CLOCK
 from ..entities import Configuration, PropertyValue
@@ -308,32 +309,35 @@ def run_measurement(store, experiments, configuration: Configuration,
     measured_any = reused_any = predicted_any = False
     try:
         for exp in experiments:
-            if store.has_values(digest, exp.identifier):
-                reused_any = True
-                continue
-            if exp.deferred:
-                # apply-on-demand (A*_pred semantics, paper §IV-4)
-                continue
-            who = f"{owner}:{threading.get_ident()}"
-            claimed = store.claim_experiment(digest, exp.identifier, who,
-                                             lease_s=claim_lease_s)
-            while not claimed:
-                # Another investigator (thread or process) is already
-                # measuring this cell: wait and reuse their result — the
-                # measure-once guarantee.  Measure ONLY after winning a claim.
-                if store.wait_for_values(digest, exp.identifier,
-                                         timeout_s=claim_timeout_s):
-                    break
-                if store.claim_exists(digest, exp.identifier):
-                    # timed out on a still-standing claim: the owner is
-                    # presumed dead — exactly one waiter steals it
-                    claimed = store.steal_claim(
-                        digest, exp.identifier, who,
-                        older_than_s=claim_timeout_s)
-                else:
-                    # owner failed and released: race for the re-claim
-                    claimed = store.claim_experiment(
-                        digest, exp.identifier, who, lease_s=claim_lease_s)
+            with tracing.span("store.claim"):
+                if store.has_values(digest, exp.identifier):
+                    reused_any = True
+                    continue
+                if exp.deferred:
+                    # apply-on-demand (A*_pred semantics, paper §IV-4)
+                    continue
+                who = f"{owner}:{threading.get_ident()}"
+                claimed = store.claim_experiment(digest, exp.identifier, who,
+                                                 lease_s=claim_lease_s)
+                while not claimed:
+                    # Another investigator (thread or process) is already
+                    # measuring this cell: wait and reuse their result — the
+                    # measure-once guarantee.  Measure ONLY after winning a
+                    # claim.
+                    if store.wait_for_values(digest, exp.identifier,
+                                             timeout_s=claim_timeout_s):
+                        break
+                    if store.claim_exists(digest, exp.identifier):
+                        # timed out on a still-standing claim: the owner is
+                        # presumed dead — exactly one waiter steals it
+                        claimed = store.steal_claim(
+                            digest, exp.identifier, who,
+                            older_than_s=claim_timeout_s)
+                    else:
+                        # owner failed and released: race for the re-claim
+                        claimed = store.claim_experiment(
+                            digest, exp.identifier, who,
+                            lease_s=claim_lease_s)
             if not claimed:
                 reused_any = True
                 continue
@@ -341,19 +345,21 @@ def run_measurement(store, experiments, configuration: Configuration,
                 # the claim is held until values durably land: any failure in
                 # measuring, converting, or storing them must free the cell
                 # so waiters take over instead of stalling until their timeout
-                values = exp.measure(configuration)
-                store.put_values(
-                    digest,
-                    [
-                        PropertyValue(
-                            name=k,
-                            value=float(v),
-                            experiment_id=exp.identifier,
-                            predicted=exp.predicted,
-                        )
-                        for k, v in values.items()
-                    ],
-                )
+                with tracing.span("measure"):
+                    values = exp.measure(configuration)
+                with tracing.span("store.values"):
+                    store.put_values(
+                        digest,
+                        [
+                            PropertyValue(
+                                name=k,
+                                value=float(v),
+                                experiment_id=exp.identifier,
+                                predicted=exp.predicted,
+                            )
+                            for k, v in values.items()
+                        ],
+                    )
             except MeasurementError as err:
                 # persist structured failure provenance BEFORE releasing the
                 # claim: the lifecycle attaches (phase, reason, attempts,

@@ -54,6 +54,7 @@ try:  # pragma: no cover - exercised implicitly by backend gating
 except Exception:  # pragma: no cover - jax-less installs
     HAVE_JAX = False
 
+from ... import tracing
 from . import bucket, full_precision
 
 __all__ = ["gp_ei", "gp_pof", "bucket"]
@@ -145,6 +146,14 @@ def _history_key(X, y, H, D, length_scale, noise, use_pallas):
             digest.digest())
 
 
+def _sent(args) -> None:
+    """Count the bytes of the host arrays among a jitted call's arguments:
+    each is copied to the device on every call."""
+    if tracing.recording():
+        tracing.count("device.h2d_bytes", sum(
+            a.nbytes for a in args if isinstance(a, (np.ndarray, np.generic))))
+
+
 def _fit_cached(X: np.ndarray, y: np.ndarray, length_scale: float,
                 noise: float, use_pallas: bool, cache: dict | None):
     """The (padded, jitted, NaN-retried) GP fit behind both scorers,
@@ -154,7 +163,11 @@ def _fit_cached(X: np.ndarray, y: np.ndarray, length_scale: float,
     Hp = bucket(H)
     key = _history_key(X, y, H, D, length_scale, noise, use_pallas)
     fit = cache.get("fit") if cache is not None else None
-    if fit is None or fit[0] != key:
+    if fit is not None and fit[0] == key:
+        tracing.count("gp.fit_cache_hit")
+        return fit
+    tracing.count("gp.refit")
+    with tracing.span("ask.fit"):
         Xh = np.zeros((Hp, D), np.float32)
         Xh[:H] = X
         yh = np.zeros(Hp, np.float32)
@@ -162,19 +175,39 @@ def _fit_cached(X: np.ndarray, y: np.ndarray, length_scale: float,
         mh = np.zeros(Hp, np.float32)
         mh[:H] = 1.0
         inv2ls2 = np.float32(0.5 / (length_scale * length_scale))
-        Linv, alpha, mu, sd, best = _gp_fit(Xh, yh, mh, inv2ls2,
-                                            np.float32(noise), use_pallas)
-        if bool(jnp.isnan(alpha).any()):
+        args = (Xh, yh, mh, inv2ls2, np.float32(noise))
+        _sent(args)
+        Linv, alpha, mu, sd, best = _gp_fit(*args, use_pallas)
+        failed = bool(jnp.isnan(alpha).any())
+        tracing.count("device.d2h_bytes", 1)
+        if failed:
             # Cholesky failed (NaN factor): one jittered retry, exactly the
             # numpy reference's second cho_factor attempt.  If this also
             # fails, the NaN surface downstream triggers the random fallback.
-            Linv, alpha, mu, sd, best = _gp_fit(Xh, yh, mh, inv2ls2,
-                                                np.float32(noise + 1e-6),
-                                                use_pallas)
-        fit = (key, Linv, alpha, mu, sd, best, Xh, mh, inv2ls2)
-        if cache is not None:
-            cache["fit"] = fit
+            tracing.count("gp.fit_retry")
+            args = (Xh, yh, mh, inv2ls2, np.float32(noise + 1e-6))
+            _sent(args)
+            Linv, alpha, mu, sd, best = _gp_fit(*args, use_pallas)
+    fit = (key, Linv, alpha, mu, sd, best, Xh, mh, inv2ls2)
+    if cache is not None:
+        cache["fit"] = fit
     return fit
+
+
+def _score_pool(name: str, program, Xc: np.ndarray, before: tuple,
+                after: tuple, use_pallas: bool) -> np.ndarray:
+    """Pad the candidate pool to its bucket, run ``program(*before, pool,
+    *after)`` on the device, and read back the scores of the real
+    candidates as float64 (padded rows are sliced off)."""
+    with tracing.span(name):
+        C = len(Xc)
+        Xcp = np.zeros((bucket(C), Xc.shape[1]), np.float32)
+        Xcp[:C] = Xc
+        args = (*before, Xcp, *after)
+        _sent(args)
+        out = np.asarray(program(*args, use_pallas))
+        tracing.count("device.d2h_bytes", out.nbytes)
+        return out[:C].astype(np.float64)
 
 
 def gp_ei(X: np.ndarray, y: np.ndarray, Xc: np.ndarray, *,
@@ -194,17 +227,13 @@ def gp_ei(X: np.ndarray, y: np.ndarray, Xc: np.ndarray, *,
     """
     if not HAVE_JAX:  # pragma: no cover - jax-less installs
         return None
-    C = len(Xc)
-    Cp = bucket(C)
     fit = _fit_cached(X, y, length_scale, noise, use_pallas, cache)
     _, Linv, alpha, mu, sd, fit_best, Xh, mh, inv2ls2 = fit
     if best is not None:
         fit_best = np.float32(best)
-    Xcp = np.zeros((Cp, X.shape[1]), np.float32)
-    Xcp[:C] = Xc
-    ei = _gp_ei(Linv, alpha, mu, sd, fit_best, Xh, mh, Xcp, inv2ls2,
-                np.float32(xi), use_pallas)
-    return np.asarray(ei)[:C].astype(np.float64)
+    return _score_pool("ask.ei", _gp_ei, Xc,
+                       (Linv, alpha, mu, sd, fit_best, Xh, mh),
+                       (inv2ls2, np.float32(xi)), use_pallas)
 
 
 def gp_pof(X: np.ndarray, z: np.ndarray, Xc: np.ndarray, *,
@@ -220,11 +249,7 @@ def gp_pof(X: np.ndarray, z: np.ndarray, Xc: np.ndarray, *,
     """
     if not HAVE_JAX:  # pragma: no cover - jax-less installs
         return None
-    C = len(Xc)
-    Cp = bucket(C)
     fit = _fit_cached(X, z, length_scale, noise, use_pallas, cache)
     _, Linv, alpha, mu, sd, _best, Xh, mh, inv2ls2 = fit
-    Xcp = np.zeros((Cp, X.shape[1]), np.float32)
-    Xcp[:C] = Xc
-    pof = _gp_pof(Linv, alpha, mu, sd, Xh, mh, Xcp, inv2ls2, use_pallas)
-    return np.asarray(pof)[:C].astype(np.float64)
+    return _score_pool("ask.pof", _gp_pof, Xc,
+                       (Linv, alpha, mu, sd, Xh, mh), (inv2ls2,), use_pallas)

@@ -36,6 +36,7 @@ from typing import Callable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from .. import tracing
 from ..actions import MeasurementError
 from ..discovery import BatchResult, DiscoverySpace
 from ..entities import Configuration
@@ -496,9 +497,10 @@ class SearchAdapter:
         results = self.ds.sample_batch(
             configs, operation_id=self.operation_id, workers=workers,
             executor=executor, backend=backend, priorities=priorities)
-        batch = [self._make_trial(result, len(self.trials) + i)
-                 for i, result in enumerate(results)]
-        self.tell(batch)
+        with tracing.span("tell"):
+            batch = [self._make_trial(result, len(self.trials) + i)
+                     for i, result in enumerate(results)]
+            self.tell(batch)
         return [t.value for t in batch]
 
     def evaluate(self, configuration) -> Optional[float]:
@@ -596,39 +598,41 @@ class Optimizer(abc.ABC):
         subsample drawn from it) is draw-for-draw identical to a fresh
         enumeration.  Adapters without the cache (ask-only stubs, legacy
         wrappers) fall back to enumerating."""
-        space = adapter.space
-        if space.finite:
-            unseen = getattr(adapter, "unseen_pool", None)
-            if unseen is not None:
-                skip = adapter.pending if not exclude \
-                    else adapter.pending | exclude
-                pool = [c for d, c in unseen().items() if d not in skip]
-            else:
-                seen = adapter.seen_digests()
-                if exclude:
-                    seen = seen | exclude
-                pool = [c for c in space.all_configurations()
-                        if c.digest not in seen]
-            if len(pool) > max_candidates:
-                idx = rng.choice(len(pool), size=max_candidates, replace=False)
-                pool = [pool[i] for i in idx]
-            return pool
-        seen = adapter.seen_digests()
-        if exclude:
-            seen |= exclude
-        out, tries = [], 0
-        while len(out) < max_candidates and tries < max_candidates * 4:
-            c = space.sample_configuration(rng)
-            if c.digest not in seen:
-                # the draw itself joins `seen`: without this, a continuous
-                # space that happens to re-draw the same point (coarse
-                # dimensions, near-exhausted pools) returns a pool with
-                # duplicates and `ask` can emit a non-distinct batch,
-                # breaking its documented contract
-                seen.add(c.digest)
-                out.append(c)
-            tries += 1
-        return out
+        with tracing.span("ask.pool"):
+            space = adapter.space
+            if space.finite:
+                unseen = getattr(adapter, "unseen_pool", None)
+                if unseen is not None:
+                    skip = adapter.pending if not exclude \
+                        else adapter.pending | exclude
+                    pool = [c for d, c in unseen().items() if d not in skip]
+                else:
+                    seen = adapter.seen_digests()
+                    if exclude:
+                        seen = seen | exclude
+                    pool = [c for c in space.all_configurations()
+                            if c.digest not in seen]
+                if len(pool) > max_candidates:
+                    idx = rng.choice(len(pool), size=max_candidates,
+                                     replace=False)
+                    pool = [pool[i] for i in idx]
+                return pool
+            seen = adapter.seen_digests()
+            if exclude:
+                seen |= exclude
+            out, tries = [], 0
+            while len(out) < max_candidates and tries < max_candidates * 4:
+                c = space.sample_configuration(rng)
+                if c.digest not in seen:
+                    # the draw itself joins `seen`: without this, a continuous
+                    # space that happens to re-draw the same point (coarse
+                    # dimensions, near-exhausted pools) returns a pool with
+                    # duplicates and `ask` can emit a non-distinct batch,
+                    # breaking its documented contract
+                    seen.add(c.digest)
+                    out.append(c)
+                tries += 1
+            return out
 
     @staticmethod
     def _history_arrays(adapter: SearchAdapter):
@@ -636,8 +640,9 @@ class Optimizer(abc.ABC):
         ok = [t for t in adapter.trials if t.value is not None]
         if not ok:
             return np.zeros((0, len(adapter.space.dimensions))), np.zeros((0,))
-        X = np.stack([adapter.space.encode(t.configuration) for t in ok])
-        y = np.array([adapter.signed(t.value) for t in ok])
+        with tracing.span("ask.encode.history"):
+            X = np.stack([adapter.space.encode(t.configuration) for t in ok])
+            y = np.array([adapter.signed(t.value) for t in ok])
         return X, y
 
     @staticmethod
@@ -677,9 +682,10 @@ class Optimizer(abc.ABC):
         """The n best-scoring candidates (with their acquisition scores), in
         score order.  Stable on ties so ``_top_n(c, s, 1)[0].configuration
         == c[np.argmax(s)]`` exactly."""
-        order = np.argsort(-score, kind="stable")
-        return [ScoredCandidate(candidates[i], float(score[i]))
-                for i in order[:n]]
+        with tracing.span("ask.rank"):
+            order = np.argsort(-score, kind="stable")
+            return [ScoredCandidate(candidates[i], float(score[i]))
+                    for i in order[:n]]
 
     @staticmethod
     def _random_n(pool: Sequence[Configuration], rng: np.random.Generator,

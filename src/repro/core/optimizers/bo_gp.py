@@ -32,6 +32,7 @@ import numpy as np
 from scipy.linalg import cho_factor, cho_solve
 from scipy.stats import norm
 
+from .. import tracing
 from .base import Optimizer, ScoredCandidate, SearchAdapter
 
 __all__ = ["GPBayesOpt"]
@@ -179,7 +180,8 @@ class GPBayesOpt(Optimizer):
         if len(y) < self.n_initial:
             return self._random_n(candidates, rng, n)
 
-        Xc = np.stack([adapter.space.encode(c) for c in candidates])
+        with tracing.span("ask.encode.pool"):
+            Xc = np.stack([adapter.space.encode(c) for c in candidates])
         if not self._constrained(adapter):
             ei = self._acquisition(X, y, Xc)
             if ei is None or bool(np.isnan(ei).all()):
