@@ -248,16 +248,96 @@ class SearchAdapter:
         # O(|Ω|) every call (see Optimizer._unseen_candidates).  Pending and
         # warm digests stay IN the cache — pending clears on tell/requeue and
         # warm configurations may legitimately be re-proposed — and are
-        # filtered per-ask.
+        # filtered per-ask.  The same walk records every digest's position
+        # in the enumeration ({digest: row}, never evicted) and a mask over
+        # those positions that tell() clears with the cache: the cached
+        # pool's positions are the mask's true entries, in the same order,
+        # found by one flatnonzero per ask rather than a lookup per
+        # candidate.
         self._unseen_cache: Optional[dict] = None
+        self._enumeration_rows: Optional[dict] = None
+        self._unseen_mask: Optional[np.ndarray] = None
+        # Unit-cube encodings, each configuration encoded once per adapter,
+        # and only when an optimizer asks for them (random search, TPE and
+        # the warm fold never do, so they pay nothing):
+        # * one row per entry of ``trials``, in order — the history is
+        #   append-only (tell, warm_start and the foreign fold append;
+        #   nothing leaves it), so each call encodes just the trials
+        #   appended since the last one, into a buffer that doubles as it
+        #   grows (see encoded_trials);
+        # * the |Ω| × d matrix of a finite space's enumeration (see
+        #   encoded_enumeration).
+        # Rows are found by position — a trial's index, a digest's row from
+        # the walk above — and never by hashing a configuration again:
+        # ``Configuration.digest`` is a sha256 of canonical JSON and costs
+        # more than the encode it would save.
+        self._encoded: Optional[np.ndarray] = None
+        self._n_encoded = 0
+        self._enumeration: Optional[np.ndarray] = None
 
     def unseen_pool(self) -> dict:
         """The cached not-yet-told enumeration of a finite space."""
         if self._unseen_cache is None:
-            self._unseen_cache = {
-                c.digest: c for c in self.space.all_configurations()
-                if c.digest not in self._history_digests}
+            rows, unseen, mask = {}, {}, []
+            for i, c in enumerate(self.space.all_configurations()):
+                d = c.digest
+                rows[d] = i
+                mask.append(d not in self._history_digests)
+                if mask[-1]:
+                    unseen[d] = c
+            self._enumeration_rows, self._unseen_cache = rows, unseen
+            self._unseen_mask = np.array(mask, bool)
         return self._unseen_cache
+
+    def unseen_rows(self, skip: set) -> np.ndarray:
+        """Positions in the enumeration of :meth:`unseen_pool`'s
+        configurations whose digest is not in ``skip``, in pool order."""
+        mask = self._unseen_mask
+        if skip:
+            mask = mask.copy()
+            for d in skip:
+                row = self._enumeration_rows.get(d)
+                if row is not None:
+                    mask[row] = False
+        return np.flatnonzero(mask)
+
+    def encoded_enumeration(self, rows: np.ndarray) -> np.ndarray:
+        """Unit-cube rows ``rows`` of the finite space's enumeration.
+
+        The matrix is built on the first call, as the cartesian product of
+        each dimension's unit grid (``Dimension.to_unit`` of its values) in
+        ``all_configurations`` order — equal row for row to
+        ``space.encode`` — and every later call gathers from it."""
+        if self._enumeration is None:
+            grids = [np.array([d.to_unit(v) for v in d.values], np.float64)
+                     for d in self.space.dimensions]
+            mesh = np.meshgrid(*grids, indexing="ij")
+            self._enumeration = np.stack([m.ravel() for m in mesh], axis=1)
+            tracing.count("encode.rows", len(self._enumeration))
+        else:
+            tracing.count("encode.rows_reused", len(rows))
+        return self._enumeration[rows]
+
+    def encoded_trials(self, keep: Sequence[bool]) -> np.ndarray:
+        """Unit-cube rows of the trials where ``keep`` (one flag per entry
+        of ``trials``) is true, in order; only trials appended since the
+        last call run through ``space.encode``."""
+        n, k = len(self.trials), self._n_encoded
+        if n > k:
+            buf = self._encoded
+            if buf is None or len(buf) < n:
+                buf = np.empty((max(n, 2 * k, 64),
+                                len(self.space.dimensions)), np.float64)
+                if k:
+                    buf[:k] = self._encoded[:k]
+                self._encoded = buf
+            encode = self.space.encode
+            for i in range(k, n):
+                buf[i] = encode(self.trials[i].configuration)
+            self._n_encoded = n
+            tracing.count("encode.rows", n - k)
+        tracing.count("encode.rows_reused", sum(keep[:k]))
+        return self._encoded[:n][np.array(keep, bool)]
 
     @property
     def space(self):
@@ -278,11 +358,15 @@ class SearchAdapter:
         trial's value in place rather than letting the failure mask it.
         """
         for t in trials:
-            self._history_digests.add(t.configuration.digest)
+            digest = t.configuration.digest
+            self._history_digests.add(digest)
             if self._unseen_cache is not None:
-                self._unseen_cache.pop(t.configuration.digest, None)
+                self._unseen_cache.pop(digest, None)
+                row = self._enumeration_rows.get(digest)
+                if row is not None:
+                    self._unseen_mask[row] = False
             if t.value is None and t.action in ("failed", FOREIGN_ACTION):
-                self._provisional_failed[t.configuration.digest] = t
+                self._provisional_failed[digest] = t
         self.trials.extend(trials)
 
     def _objective_properties(self) -> tuple:
@@ -597,15 +681,34 @@ class Optimizer(abc.ABC):
         order preserves enumeration order, so the filtered pool (and the
         subsample drawn from it) is draw-for-draw identical to a fresh
         enumeration.  Adapters without the cache (ask-only stubs, legacy
-        wrappers) fall back to enumerating."""
+        wrappers) fall back to enumerating.  :meth:`_unseen_candidates_rows`
+        returns the same pool with each candidate's enumeration row."""
+        return Optimizer._unseen_candidates_rows(
+            adapter, rng, max_candidates, exclude)[0]
+
+    @staticmethod
+    def _unseen_candidates_rows(adapter: SearchAdapter,
+                                rng: np.random.Generator,
+                                max_candidates: int = 512,
+                                exclude: Optional[set] = None) -> tuple:
+        """``(pool, rows)``: :meth:`_unseen_candidates`'s pool, the same rng
+        draws, and each candidate's position in the space's enumeration (an
+        index array threaded through the filter and the subsample), so a
+        scorer gathers the encoded pool from
+        :meth:`SearchAdapter.encoded_enumeration` instead of encoding it.
+        ``rows`` is None where the pool does not come from the cached
+        enumeration: a continuous or mixed space, whose pool is sampled, or
+        an adapter without the cache."""
         with tracing.span("ask.pool"):
             space = adapter.space
             if space.finite:
                 unseen = getattr(adapter, "unseen_pool", None)
+                rows = None
                 if unseen is not None:
                     skip = adapter.pending if not exclude \
                         else adapter.pending | exclude
                     pool = [c for d, c in unseen().items() if d not in skip]
+                    rows = adapter.unseen_rows(skip)
                 else:
                     seen = adapter.seen_digests()
                     if exclude:
@@ -616,7 +719,9 @@ class Optimizer(abc.ABC):
                     idx = rng.choice(len(pool), size=max_candidates,
                                      replace=False)
                     pool = [pool[i] for i in idx]
-                return pool
+                    if rows is not None:
+                        rows = rows[idx]
+                return pool, rows
             seen = adapter.seen_digests()
             if exclude:
                 seen |= exclude
@@ -632,17 +737,37 @@ class Optimizer(abc.ABC):
                     seen.add(c.digest)
                     out.append(c)
                 tries += 1
-            return out
+            return out, None
+
+    @staticmethod
+    def _encoded_trials(adapter: SearchAdapter, keep: list) -> np.ndarray:
+        """Unit-cube rows of the trials where ``keep`` is true: gathered
+        from the adapter's encoded history
+        (:meth:`SearchAdapter.encoded_trials`), or encoded row by row for
+        an adapter without one (ask-only stubs)."""
+        gather = getattr(adapter, "encoded_trials", None)
+        if gather is not None:
+            return gather(keep)
+        tracing.count("encode.rows", sum(keep))
+        return np.stack([adapter.space.encode(t.configuration)
+                         for t, k in zip(adapter.trials, keep) if k])
 
     @staticmethod
     def _history_arrays(adapter: SearchAdapter):
-        """(X, y) over successful trials, y in minimization orientation."""
-        ok = [t for t in adapter.trials if t.value is not None]
-        if not ok:
+        """(X, y) over successful trials, y in minimization orientation.
+
+        X gathers the valued trials' rows of the adapter's encoded history
+        (each trial encoded once, see :meth:`SearchAdapter.encoded_trials`),
+        bit-identical to encoding each trial on every ask; y is ``signed``
+        applied to the value array at once, bit-identical to signing each
+        value (a negation is exact)."""
+        values = [t.value for t in adapter.trials]
+        keep = [v is not None for v in values]
+        if not any(keep):
             return np.zeros((0, len(adapter.space.dimensions))), np.zeros((0,))
         with tracing.span("ask.encode.history"):
-            X = np.stack([adapter.space.encode(t.configuration) for t in ok])
-            y = np.array([adapter.signed(t.value) for t in ok])
+            X = Optimizer._encoded_trials(adapter, keep)
+            y = adapter.signed(np.array([v for v in values if v is not None]))
         return X, y
 
     @staticmethod
@@ -659,13 +784,13 @@ class Optimizer(abc.ABC):
         Failed trials count (labelled infeasible at tell time under a
         constrained objective); warm predictions carry None and are skipped
         — the feasibility classifier trains on evidence only."""
-        labelled = [t for t in adapter.trials if t.feasible is not None]
-        if not labelled:
+        feasible = [t.feasible for t in adapter.trials]
+        keep = [f is not None for f in feasible]
+        if not any(keep):
             return (np.zeros((0, len(adapter.space.dimensions))),
                     np.zeros((0,)))
-        X = np.stack([adapter.space.encode(t.configuration)
-                      for t in labelled])
-        z = np.array([1.0 if t.feasible else -1.0 for t in labelled])
+        X = Optimizer._encoded_trials(adapter, keep)
+        z = np.array([1.0 if f else -1.0 for f in feasible if f is not None])
         return X, z
 
     @staticmethod

@@ -157,6 +157,12 @@ class GPBayesOpt(Optimizer):
         toward ``n_initial``, skipping redundant random warmup).  Sharing
         never consumes rng draws, so solo trajectories are unchanged.
 
+        Encoding: ``X`` and the pool's ``Xc`` are gathered from the
+        adapter's unit-cube rows (each trial encoded once, a finite space's
+        enumeration encoded once), bit-identical to encoding every row on
+        every ask; a sampled pool (continuous or mixed spaces) is encoded
+        row by row.
+
         Degenerate fits degrade instead of crashing: an unfactorable Gram
         matrix or an all-NaN EI surface (posterior-std underflow on an
         all-equal history) falls back to random proposals for this step,
@@ -173,7 +179,8 @@ class GPBayesOpt(Optimizer):
         region.  The weighting never consumes rng draws, so unconstrained
         trajectories are unchanged draw-for-draw.
         """
-        candidates = self._unseen_candidates(adapter, rng, self.max_candidates)
+        candidates, rows = self._unseen_candidates_rows(
+            adapter, rng, self.max_candidates)
         if not candidates:
             return []
         X, y = self._history_arrays(adapter)
@@ -181,7 +188,11 @@ class GPBayesOpt(Optimizer):
             return self._random_n(candidates, rng, n)
 
         with tracing.span("ask.encode.pool"):
-            Xc = np.stack([adapter.space.encode(c) for c in candidates])
+            if rows is not None:
+                Xc = adapter.encoded_enumeration(rows)
+            else:
+                tracing.count("encode.rows", len(candidates))
+                Xc = np.stack([adapter.space.encode(c) for c in candidates])
         if not self._constrained(adapter):
             ei = self._acquisition(X, y, Xc)
             if ei is None or bool(np.isnan(ei).all()):
