@@ -6,10 +6,12 @@ control's and each planted fault's, over many seeds, in one process.
 
 For each seed the cell's set-up and a short window run as in a benchmark
 run; then the numbers are read twice on the same asks: once for the
-program, once for the control, the reference computed in float32 with its
-matmuls at "high" (bf16_3x), one precision below the "highest" at which the
-ask runs.  On the first three seeds each fault of ``faults.py`` is then
-planted and the cell run again.  One JSON line per seed.
+program, once for the control: the reference one precision below the
+program's, computed on the device in its place (the cell's optimizer
+family decides: for BO-GP float32 with matmuls at "high", bf16_3x, below
+the "highest" at which the ask runs; for TPE bfloat16 below float32).  On
+the first three seeds each fault of ``faults.py`` that the cell's family
+can have is then planted and the cell run again.  One JSON line per seed.
 """
 
 from __future__ import annotations
@@ -36,7 +38,8 @@ def main(argv=None) -> int:
     import harness
     harness.use_compile_cache(ROOT)
     import jax
-    from faults import FAULTS
+    from faults import BY_FAMILY, FAULTS
+    from optimizers import family_name
 
     devices = jax.devices()[:1]
 
@@ -55,11 +58,13 @@ def main(argv=None) -> int:
         finally:
             cell.close()
 
+    traffic = harness.resolve(args.workload, ROOT)[3]
+    faults = BY_FAMILY[family_name(traffic)]
     for k, seed in enumerate(int(s) for s in args.seeds.split(",")):
         line = dict(seed=seed, **reading(seed, control=True))
         if k < FAULT_SEEDS:
-            for name, plant in FAULTS.items():
-                with plant():
+            for name in faults:
+                with FAULTS[name]():
                     line[name] = reading(seed)["program"]
         print(json.dumps(line), flush=True)
     return 0

@@ -5,12 +5,19 @@ per-layer metric is a file of its own, found by the name that
 ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json`` — the configuration, whose ``kind`` names the
-  module under ``kinds/`` that builds its space and experiment;
-* ``traffic/<traffic>.json`` — the traffic mix: the BO-GP's settings and
-  pool, the store each investigation starts from and how many trials it
-  runs;
+  module under ``kinds/`` that builds its space and experiment and brings
+  its own checks (``Kind.checks(window)``: the numbers the kind's
+  guarantees are held to, such as the store giving back what was told);
+* ``traffic/<traffic>.json`` — the traffic mix: the optimizer family
+  (``optimizer``, BO-GP where absent) with its settings and pool, the store
+  each investigation starts from and how many trials it runs;
 * ``limits/<workload>.json`` — the limit of each number the check compares;
 * ``metrics/<metric>.py`` — the reader of one per-layer metric.
+
+What each optimizer family brings — its spanned optimizer, the warm-up of
+its device programs and the check of its answers against the reference —
+is one entry of ``optimizers.FAMILIES``.  The numbers a cell compares are
+its kind's checks and its family's answer gaps.
 
 The window drives ``Investigation.from_components(...).run()`` — the engine
 every investigation runs through — with the serial backend, batch 1, and a
@@ -194,87 +201,6 @@ def run_investigations(kind, traffic, base, seed: int, stream0: int,
     return w
 
 
-def warm_asks(kind, traffic, seed: int, base_measured: int) -> None:
-    """Compile every ask program the window can use: each padding bucket of
-    the history and of the pool that the traffic's sizes reach."""
-    from repro.core.optimizers.accel import bucket
-    from optimizers import make_optimizer
-    opt = make_optimizer(traffic, 0, None, [])
-    base, trials = int(traffic["base_valued_trials"]), int(traffic["trials"])
-    h_buckets = {bucket(n) for n in range(max(base, opt.n_initial),
-                                          base + trials + 1)}
-    left = kind.space.size - base_measured
-    c_buckets = {bucket(min(n, opt.max_candidates))
-                 for n in range(max(left - trials, 1), left + 1)}
-    rng = seed_rng(seed, WARM)
-    D = len(kind.space.dimensions)
-    for hp in sorted(h_buckets):
-        for cp in sorted(c_buckets):
-            X, y, Xc = rng.random((hp, D)), rng.random(hp), rng.random((cp, D))
-            opt._acquisition(X, y, Xc)
-
-
-def store_mismatches(kind, window: Window) -> int:
-    """Trials the engine told that the store does not give back as told."""
-    bad = 0
-    metric = kind.metric
-    for ds, told in window.investigations:
-        for t in told:
-            sample = ds.read_one(t.configuration)
-            if sample is None:
-                # a failed trial leaves no sample, only its failure record
-                bad += t.value is not None or not ds.failures_for(t.configuration)
-                continue
-            stored = sample.value(metric) if sample.has(metric) else None
-            if t.value is None:
-                bad += stored is not None
-            elif stored != t.value or t.value != kind.expected_value(t.configuration):
-                bad += 1
-    return bad
-
-
-# The control's matmul precision: one step below the "highest" (float32)
-# at which the ask's programs run
-CONTROL_PRECISION = "high"
-
-
-def ask_gaps(config, traffic, records: list, control: bool = False) -> dict:
-    """Widest gaps between the expected improvement each captured ask
-    ranked its pool by and the reference's; with ``control``, the reference
-    computed on the device with its matmuls at ``CONTROL_PRECISION`` takes
-    the program's place."""
-    import reference
-    enc = reference.Encoder(config["dimensions"])
-    gp = traffic["gp"]
-    hyper = dict(length_scale=gp["length_scale"], noise=gp["noise"], xi=gp["xi"])
-    out: dict = {}
-    for rec in records:
-        valued = [t for t in rec["trials"] if t.value is not None]
-        X = enc.matrix([t.configuration.as_dict() for t in valued])
-        y = np.array([t.value for t in valued], np.float64)
-        Xc = enc.matrix([c.as_dict() for c in rec["candidates"]])
-        got = rec["scores"]
-        if control:
-            got = reference.gp_ei_device(X, y, Xc, precision=CONTROL_PRECISION,
-                                         **hyper)
-        if "reference" not in rec:
-            rec["reference"] = np.nan_to_num(reference.gp_ei(X, y, Xc, **hyper),
-                                             nan=0.0)
-        want = rec["reference"]
-        # EI is measured against its peak, or against a thousandth of the
-        # history's spread where the peak is smaller: an improvement that
-        # small cannot steer the search, and float32 cannot resolve it once
-        # the pool is nearly exhausted
-        scale = max(float(np.abs(want).max()), 1e-3 * float(y.std()), 1e-300)
-        # a factorisation that failed (NaN) reads as an infinite gap
-        gap = float(np.nan_to_num(np.abs(got - want).max() / scale, nan=np.inf))
-        regret = float((want.max() - want[int(np.argmax(np.nan_to_num(got)))])
-                       / scale)
-        out["ei_gap"] = max(out.get("ei_gap", 0.0), gap)
-        out["ei_regret"] = max(out.get("ei_regret", 0.0), regret)
-    return out
-
-
 def judge(numbers: dict, limits: dict) -> tuple:
     """(correct, [(name, value, limit)]) — every limited number must be
     present and at most its limit."""
@@ -321,8 +247,10 @@ class Cell:
 
     def __init__(self, workload: str, devices, trace: bool = False,
                  root: Path = ROOT, bench_dir: Path = HERE):
+        from optimizers import family
         (self.bench, self.cell, self.config, self.traffic,
          self.limits) = resolve(workload, root, bench_dir)
+        self.family = family(self.traffic)
         self.workload = workload
         self.devices = devices
         self.trace = trace
@@ -338,7 +266,7 @@ class Cell:
             self.config, seed_rng(seed, SURFACE), self.spans)
         traffic = self.traffic
         self.base, measured = fill_base(self.kind, traffic, seed, self.workdir)
-        warm_asks(self.kind, traffic, seed, measured)
+        self.family.warm(self.kind, traffic, seed_rng(seed, WARM), measured)
         warm_traffic = dict(traffic, trials=WARM_TRIALS)
         run_investigations(self.kind, warm_traffic, self.base, seed, WARM,
                            float("inf"), Spans(), None, self.workdir)
@@ -358,12 +286,13 @@ class Cell:
         return self.window
 
     def numbers(self, control: bool = False) -> dict:
-        """The numbers the check compares; with ``control``, the control's
-        readings of the same asks instead of the program's."""
-        numbers = {} if control else {
-            "store_mismatch": float(store_mismatches(self.kind, self.window))}
-        numbers.update(ask_gaps(self.config, self.traffic,
-                                self.capture.records(), control))
+        """The numbers the check compares: the kind's checks of the window
+        and the family's gaps between the captured answers and the
+        reference; with ``control``, the control's readings of the same
+        asks alone, in the program's place."""
+        numbers = {} if control else self.kind.checks(self.window)
+        numbers.update(self.family.gaps(self.config, self.traffic,
+                                        self.capture.records(), control))
         return numbers
 
     def close(self) -> None:

@@ -47,7 +47,8 @@ def latency(values: dict, s: dict, noise: float) -> float | None:
 
 class Kind:
     """What the harness needs of a configuration kind: the space, the
-    experiment and its actions, the metric, and each point's true value."""
+    experiment and its actions, the metric, each point's true value, and
+    the checks its guarantees are held to."""
 
     def __init__(self, config: dict, rng: np.random.Generator, spans):
         self.space = make_space(config)
@@ -74,3 +75,23 @@ class Kind:
 
     def expected_value(self, configuration) -> float | None:
         return self.truth[configuration.digest]
+
+    def checks(self, window) -> dict:
+        """``store_mismatch``: trials the engine told that the store does
+        not give back as told, or whose told value is not the point's true
+        value."""
+        bad = 0
+        metric = self.metric
+        for ds, told in window.investigations:
+            for t in told:
+                sample = ds.read_one(t.configuration)
+                if sample is None:
+                    # a failed trial leaves no sample, only its failure record
+                    bad += t.value is not None or not ds.failures_for(t.configuration)
+                    continue
+                stored = sample.value(metric) if sample.has(metric) else None
+                if t.value is None:
+                    bad += stored is not None
+                elif stored != t.value or t.value != self.expected_value(t.configuration):
+                    bad += 1
+        return {"store_mismatch": float(bad)}

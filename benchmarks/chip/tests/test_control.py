@@ -2,7 +2,8 @@
 configuration states, put in the program's place, must come out as not
 correct.  ``calibrate.py`` reads it on the chip at each cell's own size;
 here it runs at test sizes.  On the CPU every matmul precision computes in
-float32, so the control (matmuls at "high") can only be read on a TPU."""
+float32, so the BO-GP control (matmuls at "high") can only be read on a
+TPU; the TPE control (bfloat16) reads the same on both."""
 
 import jax
 import pytest
@@ -10,11 +11,12 @@ import pytest
 from conftest import DATA
 
 
-def test_control_is_not_correct(tiny_root):
+@pytest.mark.parametrize("workload", ["tiny.reuse", "tiny.tpe"])
+def test_control_is_not_correct(tiny_root, workload):
     import harness
-    if jax.default_backend() != "tpu":
+    if workload == "tiny.reuse" and jax.default_backend() != "tpu":
         pytest.skip("matmul precision does not change float32 on the CPU")
-    c = harness.Cell("tiny.reuse", jax.devices()[:1], root=tiny_root,
+    c = harness.Cell(workload, jax.devices()[:1], root=tiny_root,
                      bench_dir=DATA)
     try:
         c.setup(2 ** 32 + 17)

@@ -8,11 +8,21 @@ import numpy as np
 import pytest
 
 
-@pytest.mark.parametrize("fault", ["scale_ei", "half_history", "alter_store"])
-def test_fault_turns_correct_false(run_tiny, fault):
+# the test cell of each optimizer family
+CELLS = {"bogp": "tiny.reuse", "tpe": "tiny.tpe"}
+
+
+def _cases():
+    from faults import BY_FAMILY
+    return [pytest.param(CELLS[family], fault, id=f"{CELLS[family]}-{fault}")
+            for family, faults in BY_FAMILY.items() for fault in faults]
+
+
+@pytest.mark.parametrize("cell,fault", _cases())
+def test_fault_turns_correct_false(run_tiny, cell, fault):
     from faults import FAULTS
     with FAULTS[fault]():
-        r = run_tiny("tiny.reuse", seed=2 ** 31 + 5, seconds=2.0)
+        r = run_tiny(cell, seed=2 ** 31 + 5, seconds=2.0)
     assert r["correct"] is False, r["checks"]
     failing = [n for n, c in r["checks"].items()
                if c["value"] is None or not np.isfinite(c["value"])

@@ -38,6 +38,22 @@ def test_gp_ask_counts_true_sizes():
     assert b0 == pytest.approx(b - 4 * n - 4 * n * n)
 
 
+def test_tpe_ask_counts_true_sizes():
+    import flops
+    good, bad, pool, num, cat = 320, 960, 900, 5, 1
+    f, b = flops.tpe_ask(good, bad, pool, num, cat)
+    n = good + bad
+    want = (num * (5 * pool * n + 6 * pool) + cat * (n + 4 * pool)
+            + 2 * pool * (num + cat))
+    assert f == pytest.approx(want)
+    assert b == pytest.approx(4 * (n + pool) * (num + cat) + 4 * pool)
+    # counted at the true sets and pool, never at the padded buckets
+    assert flops.tpe_ask(512, 1024, 1024, num, cat)[0] > 1.3 * f
+    # an empty bad set leaves the pairs of the good set alone
+    assert flops.tpe_ask(good, 0, pool, num, 0)[0] == pytest.approx(
+        num * (5 * pool * good + 6 * pool) + 2 * pool * num)
+
+
 def test_refit_recorded_only_when_the_history_changes():
     import numpy as np
     from optimizers import Record, make_optimizer
@@ -53,6 +69,22 @@ def test_refit_recorded_only_when_the_history_changes():
     opt._acquisition(X, y, Xc[:7])       # after a failed trial: same history
     opt._acquisition(X[:4], y[:4], Xc)
     assert record.sizes == [(5, 8, 2, True), (5, 7, 2, False), (4, 8, 2, True)]
+
+
+def test_tpe_sizes_recorded_per_scoring_call():
+    from optimizers import Record, make_optimizer
+    from repro.core import Dimension, ProbabilitySpace
+    from spans import Spans
+    traffic = {"optimizer": "tpe", "backend": "numpy", "pool": 8,
+               "tpe": {"gamma": 0.25, "bandwidth": 0.12, "n_initial": 4}}
+    space = ProbabilitySpace.make([Dimension.discrete("a", [1, 2, 3]),
+                                   Dimension.categorical("b", ["x", "y"])])
+    configs = list(space.all_configurations())
+    record = Record(Spans(), None, float("inf"))
+    opt = make_optimizer(traffic, 0, record, [])
+    opt._score(space, configs[:2], configs[2:5], configs)
+    opt._score(space, configs[:1], [], configs[:4])
+    assert record.sizes == [(2, 3, 6, 1, 1), (1, 0, 4, 1, 1)]
 
 
 def _load(name):
@@ -87,6 +119,32 @@ def test_metric_readers():
     empty = dict(ctx, trace=dict(trace, module_time={}))
     assert _load("gp_device_ms.per_ask")(empty) is None
     assert _load("gp_ask_roofline")(empty) is None
+
+
+def test_tpe_metric_readers():
+    import flops
+    import harness
+    from spans import Spans
+    w = harness.Window()
+    w.t0, w.t1 = 10.0, 20.0
+    spans = Spans()
+    spans.records = [("ask", 11.0, 11.5), ("ask", 12.0, 12.25),
+                     ("ask", 5.0, 6.0)]  # set-up: outside the window
+    w.record = types.SimpleNamespace(sizes=[(300, 900, 1000, 5, 1),
+                                            (301, 900, 999, 5, 1)])
+    peaks = {"flops_bf16": 1e12, "hbm_bytes_per_s": 1e11}
+    trace = {"window_s": 10.0, "busy_s": 2.5,
+             "module_time": {"jit__tpe_scores": 0.004, "jit__gp_ei": 0.001}}
+    ctx = {"window": w, "spans": spans, "trace": trace, "peaks": peaks,
+           "compiles_in_window": 0}
+    assert _load("tpe_device_ms.per_ask")(ctx) == pytest.approx(2.0)
+    least = sum(max(flops.tpe_ask(*s)[0] / 1e12, flops.tpe_ask(*s)[1] / 1e11)
+                for s in w.record.sizes)
+    assert _load("tpe_ask_roofline")(ctx) == pytest.approx(100 * least / 0.004)
+    # a BO-GP window runs no TPE program: nothing to read, never 0
+    gp = dict(ctx, trace=dict(trace, module_time={"jit__gp_ei": 0.001}))
+    assert _load("tpe_device_ms.per_ask")(gp) is None
+    assert _load("tpe_ask_roofline")(gp) is None
 
 
 def test_trace_summary_arithmetic():
@@ -169,6 +227,35 @@ def test_tiny_reuse_cell(run_tiny):
     assert list(r)[-1] == "checks"
     assert r["checks"]["store_mismatch"]["value"] == 0
     assert r["checks"]["ei_gap"]["value"] < 1e-3
+
+
+def test_tiny_reuse_checks_come_from_the_kind_and_the_family(tiny_root):
+    """The BO-GP cell compares exactly ``store_mismatch``, which its kind
+    brings, and ``ei_gap``, which the BO-GP family brings."""
+    import jax
+    import harness
+    import optimizers
+    c = harness.Cell("tiny.reuse", jax.devices()[:1], root=tiny_root,
+                     bench_dir=DATA)
+    try:
+        c.setup(2 ** 33 + 3)
+        c.run_window(1.0)
+        assert c.family is optimizers.FAMILIES["bogp"]
+        assert set(c.kind.checks(c.window)) == {"store_mismatch"}
+        assert set(c.numbers()) == {"store_mismatch", "ei_gap", "ei_regret"}
+    finally:
+        c.close()
+    assert set(c.limits) == {"store_mismatch", "ei_gap"}
+
+
+def test_tiny_tpe_cell(run_tiny):
+    r = run_tiny("tiny.tpe", seed=2 ** 32 + 7, seconds=2.0)
+    assert r["correct"] is True, r
+    assert r["attempted"] > 0 and r["failed"] == 0
+    assert list(r)[-1] == "checks"
+    assert set(r["checks"]) == {"store_mismatch", "tpe_gap"}
+    assert r["checks"]["store_mismatch"]["value"] == 0
+    assert r["checks"]["tpe_gap"]["value"] < 1e-4
 
 
 def test_tiny_reuse_cell_traced(run_tiny, monkeypatch):
